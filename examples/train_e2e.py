@@ -2,9 +2,11 @@
 ALL input preprocessing disaggregated to service workers (the paper's
 architecture at laptop scale).
 
-Pipeline (on workers): synthetic corpus -> tokenize -> pack to seq_len ->
-batch.  Trainer (this process): jitted train_step, checkpoint every 50
-steps, resumable after crash via --resume.
+Pipeline (on workers): ``repro.data.pipelines.lm_pipeline`` — synthetic
+Zipf documents packed into seq_len-token sequences -> batch.  Trainer
+(this process): jitted train_step, checkpoint every 50 steps, resumable
+after crash via --resume.  Compiles go to the persistent cache
+(``repro.train.use_compile_cache``).
 
 Run:   PYTHONPATH=src python examples/train_e2e.py --steps 200
 Quick: PYTHONPATH=src python examples/train_e2e.py --steps 20 --tiny
@@ -17,7 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import start_service
-from repro.data import Dataset
+from repro.data.pipelines import lm_pipeline
 from repro.feed import DeviceFeeder
 from repro.models import build_model
 from repro.train import (
@@ -27,36 +29,11 @@ from repro.train import (
     make_train_step,
     restore_checkpoint,
     save_checkpoint,
+    use_compile_cache,
 )
 
 SEQ = 256
 BATCH = 8
-
-
-def corpus_pipeline(vocab: int, num_docs: int = 100_000) -> Dataset:
-    """Synthetic 'documents' tokenized and packed on the WORKERS."""
-
-    def make_doc(i):
-        rng = np.random.default_rng(int(i))
-        n = int(rng.integers(64, 512))
-        # zipf-ish token ids — a real tokenizer's output distribution
-        toks = np.minimum(rng.zipf(1.3, n), vocab - 1).astype(np.int64)
-        return toks
-
-    def pack(doc):
-        out = np.zeros((SEQ + 1,), np.int64)
-        n = min(len(doc), SEQ + 1)
-        out[:n] = doc[:n]
-        return {"tokens": out[:-1], "labels": out[1:]}
-
-    return (
-        Dataset.range(num_docs)
-        .shuffle(2048, seed=0)
-        .map(make_doc, stochastic=False)
-        .map(pack)
-        .batch(BATCH, drop_remainder=True)
-        .prefetch(8)
-    )
 
 
 def build(tiny: bool):
@@ -83,6 +60,7 @@ def main() -> None:
     ap.add_argument("--tiny", action="store_true")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg, model = build(args.tiny)
     n_params = sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(
@@ -101,7 +79,7 @@ def main() -> None:
 
     service = start_service(num_workers=args.workers)
     try:
-        ds = corpus_pipeline(cfg.vocab_size).distribute(
+        ds = lm_pipeline(cfg.vocab_size, SEQ, BATCH).distribute(
             service=service, processing_mode="dynamic"
         )
         # the feeder replaces the manual next(it) + jnp.asarray loop:

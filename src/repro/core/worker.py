@@ -759,7 +759,7 @@ class Worker:
         self.tracer = Tracer(process=f"worker:{self.worker_id}")
         # worker_processes=0 keeps the paper's in-thread engine; N>=1 runs
         # pipelines in a pool of N forked children (data.executors)
-        self._executor = make_executor(worker_processes)
+        self._executor = make_executor(worker_processes, self.registry)
         # host identity for client-side shm:// co-location detection;
         # advertised in register_worker tags and the ping response
         self._host_key = host_key or socket.gethostname()

@@ -29,13 +29,19 @@ Invariants both engines honour:
 Failure contract: a child that dies or errors *before yielding anything*
 triggers a transparent in-thread retry (covers graphs that capture
 process-local state a fork can't see, e.g. ``__local__/`` registry tokens
-created after the child forked).  A child lost *mid-stream* raises
-``ExecutorError`` — the worker's task machinery already treats a runner
-error as a task failure and the dispatcher reassigns.
+created after the child forked).  Every such retry, and every pipeline the
+pool could not hand to a child at all, counts on the owning registry's
+``executor_inthread_fallbacks_total`` counter, so a pool that silently
+degraded to the in-thread engine is visible.  A child lost *mid-stream*
+raises ``ExecutorError`` — the worker's task machinery already treats a
+runner error as a task failure and the dispatcher reassigns.
 
 The pool uses the ``fork`` start method deliberately: forked children
 inherit ``data.registry._LOCAL_FNS``, so lambda/closure transforms that
 were registered before the child started resolve without being picklable.
+Children fork lazily, on the first request routed to them, so in a trainer
+they fork after the accelerator backend is up.  They never touch JAX: a
+pipeline is numpy and Python only, and the device stays with the parent.
 """
 from __future__ import annotations
 
@@ -48,6 +54,7 @@ import time
 import zlib
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+from ..obs.registry import MetricsRegistry
 from .iterators import ExecContext, Knob, build_iterator
 
 logger = logging.getLogger(__name__)
@@ -239,10 +246,15 @@ def _child_main(ctrl_q, out_q) -> None:
 class ProcessPoolExecutor(PipelineExecutor):
     """Run pipelines in ``processes`` forked children with request affinity."""
 
-    def __init__(self, processes: int):
+    def __init__(self, processes: int, registry: Optional[MetricsRegistry] = None):
         import multiprocessing
 
         self.width = max(1, int(processes))
+        registry = registry if registry is not None else MetricsRegistry()
+        self._fallbacks = registry.counter(
+            "executor_inthread_fallbacks_total",
+            "pipelines the process pool ran in the worker's own thread",
+        )
         self._mp = multiprocessing.get_context("fork")
         self._children: List[Optional[Any]] = [None] * self.width
         self._ctrl: List[Optional[Any]] = [None] * self.width
@@ -254,7 +266,7 @@ class ProcessPoolExecutor(PipelineExecutor):
         self._last_knob: Dict[str, Dict[Tuple[int, str], int]] = {}
         self._rid_counter = itertools.count()
         self._stopping = threading.Event()
-        self._fallback = InThreadExecutor()
+        self._inthread = InThreadExecutor()
 
     # -- child lifecycle ---------------------------------------------------
     def _ensure_child(self, i: int) -> Tuple[Any, Any]:
@@ -341,6 +353,13 @@ class ProcessPoolExecutor(PipelineExecutor):
                     knob.value = int(child_val)
                     last[(idx, kind)] = int(child_val)
 
+    def _fallback(self, graph, ctx, affinity, offset):
+        """Run the request in the calling thread, and count that it did."""
+        self._fallbacks.inc()
+        return self._inthread.iterate(
+            graph, ctx, affinity=affinity, offset=offset
+        )
+
     # -- the engine ----------------------------------------------------------
     def iterate(self, graph, ctx, *, affinity, offset=0):
         child_idx = zlib.crc32(str(affinity).encode("utf-8")) % self.width
@@ -357,9 +376,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                 "graph not picklable for executor pool (%r); running in-thread",
                 e,
             )
-            yield from self._fallback.iterate(
-                graph, ctx, affinity=affinity, offset=offset
-            )
+            yield from self._fallback(graph, ctx, affinity, offset)
             return
         try:
             ctrl, proc = self._ensure_child(child_idx)
@@ -371,9 +388,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                 child_idx,
                 e,
             )
-            yield from self._fallback.iterate(
-                graph, ctx, affinity=affinity, offset=offset
-            )
+            yield from self._fallback(graph, ctx, affinity, offset)
             return
 
         inq: "queue.Queue[Any]" = queue.Queue()
@@ -399,9 +414,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                 logger.warning(
                     "executor dispatch failed (%r); running in-thread", e
                 )
-                yield from self._fallback.iterate(
-                    graph, ctx, affinity=affinity, offset=offset
-                )
+                yield from self._fallback(graph, ctx, affinity, offset)
                 return
             while True:
                 if ctx.stop_event.is_set():
@@ -439,9 +452,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                             "(%s); running in-thread",
                             err_repr,
                         )
-                        yield from self._fallback.iterate(
-                            graph, ctx, affinity=affinity, offset=offset
-                        )
+                        yield from self._fallback(graph, ctx, affinity, offset)
                         return
                     raise ExecutorError(f"pipeline failed in child: {err_repr}")
                 elif kind == "died":
@@ -451,9 +462,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                             "running in-thread",
                             child_idx,
                         )
-                        yield from self._fallback.iterate(
-                            graph, ctx, affinity=affinity, offset=offset
-                        )
+                        yield from self._fallback(graph, ctx, affinity, offset)
                         return
                     raise ExecutorError(
                         f"executor child {child_idx} died mid-request"
@@ -501,9 +510,12 @@ class ProcessPoolExecutor(PipelineExecutor):
                 pass
 
 
-def make_executor(processes: int) -> PipelineExecutor:
+def make_executor(
+    processes: int, registry: Optional[MetricsRegistry] = None
+) -> PipelineExecutor:
     """Build the engine for ``worker_processes=N`` (0/1-thread semantics: 0
-    keeps the paper's in-thread engine; N >= 1 runs an N-child pool)."""
+    keeps the paper's in-thread engine; N >= 1 runs an N-child pool whose
+    fallback counter lives in ``registry``)."""
     if processes and processes > 0:
-        return ProcessPoolExecutor(processes)
+        return ProcessPoolExecutor(processes, registry)
     return InThreadExecutor()

@@ -9,6 +9,9 @@ open pipelines with *registered* (serializable) UDFs:
   CPU cost ⇒ input-bound jobs; the horizontal scale-out benchmark uses it.
 * ``nlp_pipeline``   — tokenized variable-length sequences → (optional)
   bucket-by-length → padded batch.  Feeds the coordinated-reads benchmark.
+* ``lm_pipeline``    — synthetic Zipf documents packed into fixed-length
+  next-token sequences → batch.  Feeds the LM training examples and
+  ``chip_smoke.py``.
 
 Work knobs are explicit (``work_factor``) so benchmarks can dial
 preprocessing cost to reproduce both input-bound and model-bound regimes.
@@ -46,6 +49,26 @@ def synthetic_token_seq(
     ln = int(np.clip(rng.lognormal(mean=4.0, sigma=0.8), 4, max_len))
     toks = rng.randint(1, vocab, size=(ln,), dtype=np.int32)
     return {"tokens": toks, "length": np.int64(ln), "index": np.int64(int(i))}
+
+
+@register("packed_lm_sequence")
+def packed_lm_sequence(
+    i: Any, *, seq_len: int, vocab: int, seed: int = 0
+) -> Dict[str, np.ndarray]:
+    """Packed sequence ``i``: documents of 64–511 Zipf-distributed token
+    ids (1 ≤ id < vocab, a real tokenizer's skew) drawn from a generator
+    seeded by ``(seed, i)`` and concatenated until ``seq_len + 1`` tokens,
+    then split into next-token ``tokens`` and ``labels``.  A pure function
+    of ``i``, so any execution order yields the same sequence for ``i``."""
+    rng = np.random.default_rng((seed, int(i)))
+    out = np.empty(seq_len + 1, np.int32)
+    n = 0
+    while n < out.size:
+        doc = np.minimum(rng.zipf(1.3, int(rng.integers(64, 512))), vocab - 1)
+        take = min(doc.size, out.size - n)
+        out[n : n + take] = doc[:take]
+        n += take
+    return {"tokens": out[:-1], "labels": out[1:]}
 
 
 # ---------------------------------------------------------------------------
@@ -203,6 +226,26 @@ def nlp_pipeline(
         )
         ds = ds.flat_map(identity_window)
     return ds
+
+
+def lm_pipeline(
+    vocab: int,
+    seq_len: int,
+    batch_size: int,
+    num_sequences: int = 100_000,
+    shuffle_buffer: int = 2048,
+    seed: int = 0,
+) -> Dataset:
+    """LM pretraining input, run on the workers: shuffled sequence ids →
+    packed Zipf documents (``packed_lm_sequence``) → batches of
+    ``batch_size`` × ``seq_len`` int32 tokens and labels."""
+    return (
+        Dataset.range(num_sequences)
+        .shuffle(shuffle_buffer, seed=seed)
+        .map(packed_lm_sequence, seq_len=seq_len, vocab=vocab, seed=seed)
+        .batch(batch_size, drop_remainder=True)
+        .prefetch(8)
+    )
 
 
 def materialized(ds: Dataset, snapshot_path: str, tail: bool = False) -> Dataset:

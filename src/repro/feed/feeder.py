@@ -42,6 +42,8 @@ import threading
 import time
 from typing import Any, Iterator, Optional
 
+import jax
+
 from .metrics import FeedMetrics, StallWindow
 from .sharded import host_layout, infer_batch_shardings, leaf_nbytes, put_batch, resolve_shardings
 
@@ -153,15 +155,20 @@ class DeviceFeeder:
     def _make_session(self) -> Any:
         """Register this host's consumer session per the sharding mode.
 
-        The feeder opts into ``zero_copy=True``: with a co-located worker
-        the shm ring's borrowed views feed ``jax.device_put`` directly —
-        host batch bytes are copied exactly once, shm slot → device.  The
-        lease contract (views valid until the next ``next(it)``) holds
-        because ``_run`` places each batch on device before fetching the
-        next one.  Remote workers are unaffected (tcp path decodes owned
-        arrays).
+        On an accelerator the feeder opts into ``zero_copy=True``: with a
+        co-located worker the shm ring's borrowed views feed
+        ``jax.device_put`` directly — host batch bytes are copied exactly
+        once, shm slot → device.  The lease contract (views valid until
+        the next ``next(it)``) holds because ``_run`` waits until each
+        batch's transfer has completed before fetching the next one:
+        ``device_put`` may return while the copy still reads the host
+        buffer.  The CPU backend's ``device_put`` does not copy an aligned
+        host buffer at all — the device array aliases it, and would change
+        when the worker reuses the slot — so on the CPU the client copies
+        each batch out of the ring instead.  Remote workers are unaffected
+        (tcp path decodes owned arrays).
         """
-        overrides: dict = {"zero_copy": True}
+        overrides: dict = {"zero_copy": jax.default_backend() != "cpu"}
         if self.sharding_mode == "static":
             # Coordinated-reads consumer indexing (§3.6): round r, slot
             # host_index — per-host static sharding of every round's window.
@@ -227,7 +234,8 @@ class DeviceFeeder:
             elif self._mesh is not None and self._plan is not None:
                 self._shardings = infer_batch_shardings(batch, self._mesh, self._plan)
             self._shardings_ready = True
-        return put_batch(batch, self._shardings)
+        # wait for the copy: the next fetch hands the batch's ring slot back
+        return jax.block_until_ready(put_batch(batch, self._shardings))
 
     def _put(self, item: Any) -> bool:
         while not self._closed.is_set():

@@ -8,10 +8,12 @@ segments overlap; each segment computes a partial online-softmax
 partials into the final output — the classic flash-decoding two-phase plan,
 adapted so phase 1 is one Pallas kernel and phase 2 is fused XLA.
 
-Grid: (B, Hkv, num_splits); block tiling (VMEM):
+Grid: (B, Hkv, num_splits); block tiling (VMEM), over a head-major cache
+(the wrapper transposes (B, S, Hkv, D) to (B, Hkv, S, D) so the two minor
+block dims are (rows, D), the tiling Mosaic accepts):
   q     (1, 1, G, D)      — all G grouped q-heads of this kv head
-  k/v   (1, block_s, 1, D)
-  out   acc (1, 1, num_splits, G, D) f32; m/l (1, 1, num_splits, G)
+  k/v   (1, 1, seg, D)
+  out   acc (1, 1, 1, G, D) f32; m/l (1, 1, 1, G, 1) f32 columns
 
 The segment loop over block_s-sized tiles runs INSIDE the kernel
 (fori_loop over VMEM loads) so each grid step reads its whole segment while
@@ -51,10 +53,10 @@ def _decode_kernel(
     nblocks = seg // block_s
 
     def body(i, carry):
-        m, l, acc = carry  # (G,), (G,), (G, D)
-        lo = i * block_s  # offset within this segment
-        k = k_ref[0, pl.dslice(lo, block_s), 0, :]  # (block_s, D)
-        v = v_ref[0, pl.dslice(lo, block_s), 0, :]
+        m, l, acc = carry  # (G, 1), (G, 1), (G, D)
+        lo = pl.multiple_of(i * block_s, block_s)  # offset within segment
+        k = k_ref[0, 0, pl.dslice(lo, block_s), :]  # (block_s, D)
+        v = v_ref[0, 0, pl.dslice(lo, block_s), :]
         s = jax.lax.dot_general(
             q.astype(k.dtype), k,
             (((1,), (1,)), ((), ())),
@@ -67,25 +69,25 @@ def _decode_kernel(
         if window > 0:
             mask &= kv_pos > length - 1 - window
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_new = l * alpha + p.sum(axis=-1)
+        l_new = l * alpha + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return m_new, l_new, acc * alpha[:, None] + pv
+        return m_new, l_new, acc * alpha + pv
 
-    m0 = jnp.full((G,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((G,), jnp.float32)
+    m0 = jnp.full((G, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((G, 1), jnp.float32)
     a0 = jnp.zeros((G, D), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, nblocks, body, (m0, l0, a0))
     acc_ref[0, 0, 0, :, :] = acc
-    m_ref[0, 0, 0, :] = m
-    l_ref[0, 0, 0, :] = l
+    m_ref[0, 0, 0, :, :] = m
+    l_ref[0, 0, 0, :, :] = l
 
 
 def decode_attention_fwd(
@@ -116,6 +118,8 @@ def decode_attention_fwd(
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
     qg = q.reshape(B, Hkv, G, D)
+    k_cache = jnp.swapaxes(k_cache, 1, 2)  # head-major (B, Hkv, S, D)
+    v_cache = jnp.swapaxes(v_cache, 1, 2)
     grid = (B, Hkv, num_splits)
     kern = functools.partial(
         _decode_kernel,
@@ -131,21 +135,25 @@ def decode_attention_fwd(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), lambda b, h, s, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, seg, 1, D), lambda b, h, s, *_: (b, s, h, 0)),
-                pl.BlockSpec((1, seg, 1, D), lambda b, h, s, *_: (b, s, h, 0)),
+                pl.BlockSpec((1, 1, seg, D), lambda b, h, s, *_: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, seg, D), lambda b, h, s, *_: (b, h, s, 0)),
             ],
             out_specs=[
                 pl.BlockSpec(
                     (1, 1, 1, G, D), lambda b, h, s, *_: (b, h, s, 0, 0)
                 ),
-                pl.BlockSpec((1, 1, 1, G), lambda b, h, s, *_: (b, h, s, 0)),
-                pl.BlockSpec((1, 1, 1, G), lambda b, h, s, *_: (b, h, s, 0)),
+                pl.BlockSpec(
+                    (1, 1, 1, G, 1), lambda b, h, s, *_: (b, h, s, 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, 1, G, 1), lambda b, h, s, *_: (b, h, s, 0, 0)
+                ),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, num_splits, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, num_splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, num_splits, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, num_splits, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, num_splits, G, 1), jnp.float32),
         ],
         compiler_params=pltpu.compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel"),
@@ -154,6 +162,7 @@ def decode_attention_fwd(
     )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
 
     # phase 2: merge split partials (tiny, fused by XLA)
+    m, l = m[..., 0], l[..., 0]  # (B, Hkv, ns, G)
     m_g = m.max(axis=2, keepdims=True)  # (B, Hkv, 1, G)
     w = jnp.exp(m - m_g)  # (B, Hkv, ns, G)
     l_tot = (l * w).sum(axis=2)  # (B, Hkv, G)

@@ -5,10 +5,12 @@ Grid: (B, Hq, num_q_blocks, num_kv_blocks) — the last dimension is
 lives in VMEM scratch and is carried across KV blocks; the output block is
 emitted on the final KV iteration.
 
-BlockSpec tiling (per grid step, all VMEM):
-  q    (1, block_q, 1, D)     — one q-head tile
-  k/v  (1, block_k, 1, D)     — the GQA kv head is q_head // group_size
-  out  (1, block_q, 1, D)
+BlockSpec tiling (per grid step, all VMEM), over head-major operands —
+the wrapper transposes (B, S, H, D) to (B, H, S, D) so that the two minor
+block dims are (rows, D), which is the tiling Mosaic accepts:
+  q    (1, 1, block_q, D)     — one q-head tile
+  k/v  (1, 1, block_k, D)     — the GQA kv head is q_head // group_size
+  out  (1, 1, block_q, D)
   scratch: acc (block_q, D) f32, m/l (block_q, MINOR) f32
 
 block_q/block_k default to 128/256: q·kᵀ tiles are (128, 256) f32 = 128 KiB,
@@ -70,9 +72,9 @@ def _fa_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :]  # (block_q, D)
-        k = k_ref[0, :, 0, :]  # (block_k, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]  # (block_q, D)
+        k = k_ref[0, 0]  # (block_k, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k,
             (((1,), (1,)), ((), ())),
@@ -108,7 +110,7 @@ def _fa_kernel(
     @pl.when(ki == nk - 1)
     def _emit():
         l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
@@ -139,6 +141,8 @@ def flash_attention_fwd(
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
+    # head-major: the (block, D) tile is the minor pair of every block
+    q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
     grid = (B, Hq, nq, nk)
     kern = functools.partial(
         _fa_kernel,
@@ -156,12 +160,12 @@ def flash_attention_fwd(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * block_q, Hq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, nq * block_q, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, MINOR), jnp.float32),
@@ -172,4 +176,4 @@ def flash_attention_fwd(
         ),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return jnp.swapaxes(out[:, :, :Sq], 1, 2)

@@ -71,7 +71,18 @@ def _router_kernel(
     all_choices = onehots[0]
     for j in range(1, k):
         all_choices = all_choices + onehots[j]  # (Tb, E) ∈ {0,1}
-    before_tok = jnp.cumsum(all_choices, axis=0) - all_choices
+    # exclusive prefix sum over tokens as a strictly-lower-triangular mask
+    # matmul (Mosaic has no cumsum); 0/1 operands and sums <= block_t are
+    # exact in f32
+    strict = (
+        jax.lax.broadcasted_iota(jnp.int32, (Tb, Tb), 0)
+        > jax.lax.broadcasted_iota(jnp.int32, (Tb, Tb), 1)
+    ).astype(jnp.float32)
+    before_tok = jax.lax.dot_general(
+        strict, all_choices.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
     prior_round = jnp.zeros_like(all_choices)
     for j in range(k):
         pos = counts[None, :] + before_tok + prior_round

@@ -1,16 +1,13 @@
-"""Version-compat shim for the Pallas TPU API surface this repo uses.
+"""The Pallas TPU API surface the kernels use, in the installed jax's spelling.
 
-jax has renamed pieces of the Pallas API across releases — most notably
-``pltpu.TPUCompilerParams`` (jax <= 0.4.x / 0.5.x) vs
-``pltpu.CompilerParams`` (newer) — and kernels that pin one spelling break
-loudly 38 tests at a time when the toolchain moves.  Every kernel in
-``repro.kernels`` imports the symbols it needs from here instead of from
-``jax.experimental.pallas.tpu`` directly, so a jax bump is absorbed (or
-rejected) in exactly one module.
+The repo supports one jax: 0.9.0.  Every kernel in ``repro.kernels``
+imports the TPU-specific names it needs from here instead of from
+``jax.experimental.pallas.tpu`` directly, so a jax upgrade that renames
+one of them is absorbed in exactly one module.
 
-``tests/test_pallas_compat.py`` is the drift canary: it asserts each of
-these names resolves against the installed jax, so the next incompatible
-bump fails at one readable assert instead of scattered tracebacks.
+``tests/test_pallas_compat.py`` pins each name against the installed jax,
+so an incompatible upgrade fails at one readable assert instead of in
+scattered kernel tracebacks.
 """
 from __future__ import annotations
 
@@ -21,50 +18,20 @@ __all__ = [
     "JAX_VERSION",
     "VMEM",
     "SMEM",
-    "ANY",
     "PrefetchScalarGridSpec",
     "compiler_params",
 ]
 
 JAX_VERSION: str = jax.__version__
 
-# --- compiler params -------------------------------------------------------
-# jax <= 0.5: pltpu.TPUCompilerParams; newer jax renamed it CompilerParams.
-_TPUCompilerParams = getattr(pltpu, "TPUCompilerParams", None) or getattr(
-    pltpu, "CompilerParams", None
-)
-if _TPUCompilerParams is None:  # pragma: no cover - future drift canary
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither TPUCompilerParams nor "
-        f"CompilerParams (jax {JAX_VERSION}); update repro.kernels.pallas_compat"
-    )
+# scratch shapes and scalar operands
+VMEM = pltpu.VMEM
+SMEM = pltpu.SMEM
+
+# grid spec of the scalar-prefetch kernels (decode_attention, fused_augment)
+PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
 
 
 def compiler_params(*, dimension_semantics: tuple[str, ...], **kw):
-    """Build the TPU compiler-params object under either jax spelling."""
-    return _TPUCompilerParams(dimension_semantics=dimension_semantics, **kw)
-
-
-# --- memory spaces & scratch shapes ---------------------------------------
-# pltpu.VMEM((shape), dtype) is the scratch-shape convention for every jax
-# this repo supports; alias it here so kernels have a single import site.
-VMEM = pltpu.VMEM
-SMEM = pltpu.SMEM
-ANY = pltpu.ANY
-
-# --- grid specs ------------------------------------------------------------
-# PrefetchScalarGridSpec exists in every jax this shim supports.  If a
-# future jax drops it, fail at construction with a message naming the
-# symbol (the shim's contract: one readable error, not a TypeError deep in
-# pallas internals from an unverified substitute).
-if hasattr(pltpu, "PrefetchScalarGridSpec"):
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-else:  # pragma: no cover - future drift canary
-
-    def PrefetchScalarGridSpec(*args, **kw):
-        raise ImportError(
-            "jax.experimental.pallas.tpu no longer exposes "
-            f"PrefetchScalarGridSpec (jax {JAX_VERSION}); port the scalar-"
-            "prefetch kernels (decode_attention, fused_augment) to this "
-            "jax's convention and update repro.kernels.pallas_compat"
-        )
+    """The TPU compiler-params object every kernel passes to pallas_call."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics, **kw)

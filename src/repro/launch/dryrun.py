@@ -1,5 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture × input shape × mesh) cell:
@@ -18,13 +16,22 @@ Usage:
 The --all driver runs each cell in a fresh subprocess (compile arenas are
 per-process; a wedged cell cannot poison the sweep) and skips cells whose
 JSON already exists (resumable).
+
+The production mesh is 512 host (CPU) devices standing in for the pod:
+run as a program, this module sets ``JAX_PLATFORMS=cpu`` and the
+host-device count before JAX is imported, so on a TPU host it still builds
+the 512-device mesh instead of finding the one local chip.
 """
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
 import traceback
+
+# the chip the production mesh models; its devices here are CPU stand-ins
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "experiments", "dryrun")
 
@@ -156,7 +163,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
     print(f"cost_analysis: flops={cost.get('flops')} bytes={cost.get('bytes accessed')}")
     hlo = compiled.as_text()
     rep = build_report(
-        arch, shape_name, mesh_name, chips, cost, hlo, mem, cfg, shape, shape.kind
+        arch, shape_name, mesh_name, chips, cost, hlo, mem, cfg, shape, shape.kind,
+        device_kind=TARGET_DEVICE_KIND,
     )
     record.update(
         status="OK",
@@ -306,4 +314,6 @@ def summarize(out_dir: str) -> None:
 
 
 if __name__ == "__main__":
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -11,13 +11,30 @@ meshes.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from ..dist.context import ShardingPlan
+
+
+def make_mesh(
+    shape: Sequence[int], axes: Sequence[str], devices: Optional[Sequence] = None
+) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    jax 0.9 makes ``Explicit`` axes by default, and explicit sharding
+    rejects the model's GSPMD-style gathers (an embedding lookup whose
+    table and token ids both shard over "data").  The sharding rules and
+    ``with mesh, use_plan(plan)`` are written for the compiler-propagated
+    (``Auto``) mode.
+    """
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(shape),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -30,7 +47,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"mesh {shape} needs {n} devices, have {len(devices)} — run via "
             "launch/dryrun.py which sets xla_force_host_platform_device_count"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_plan(mesh: Mesh, *, fsdp_over_pod: bool = False,
@@ -51,4 +68,4 @@ def make_test_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
     n = data * model
     if len(jax.devices()) < n:
         return None
-    return jax.make_mesh((data, model), ("data", "model"), devices=jax.devices()[:n])
+    return make_mesh((data, model), ("data", "model"), devices=jax.devices()[:n])

@@ -2,9 +2,12 @@
 
 Three terms per (arch × shape × mesh), in seconds:
 
-    compute    = HLO_FLOPs       / (chips × 197e12 bf16 FLOP/s)
-    memory     = HLO_bytes       / (chips × 819e9  B/s HBM)
-    collective = collective_B    / (chips × 50e9   B/s per ICI link)
+    compute    = HLO_FLOPs       / (chips × peak bf16 FLOP/s)
+    memory     = HLO_bytes       / (chips × peak HBM B/s)
+    collective = collective_B    / (chips × ICI B/s per link)
+
+with the peaks of the target chip, looked up by its ``device_kind`` in
+``PEAKS``; a chip that is not in the table is an error, never a default.
 
 ``cost_analysis()`` on the SPMD-partitioned module reports PER-DEVICE flops
 and bytes — but counts while-loop (scan) bodies ONCE, so we use the
@@ -23,10 +26,32 @@ import re
 from dataclasses import asdict, dataclass
 from typing import Any, Dict, Optional
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link (conservative single-link figure)
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per ICI link
+
+
+# Per-chip peaks keyed by jax ``device_kind`` ("TPU v5 lite" is the v5e).
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+# (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -132,9 +157,13 @@ def build_report(
     cfg,
     shape,
     kind: str,
+    *,
+    device_kind: str,
     note: str = "",
 ) -> RooflineReport:
     from . import hlo_cost
+
+    peak = peaks_for(device_kind)
 
     hc = hlo_cost.analyze(hlo_text)
     flops_dev = float(hc.flops)  # trip-count-aware, per device (post-SPMD)
@@ -145,9 +174,9 @@ def build_report(
     coll["xla_cost_analysis_flops_scan_once"] = float(cost.get("flops", 0.0))
     coll_dev = float(hc.collective_bytes)
 
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    collective_s = coll_dev / ICI_BW
+    compute_s = flops_dev / peak.flops
+    memory_s = bytes_dev / peak.hbm_bw
+    collective_s = coll_dev / peak.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape, kind, chips)

@@ -1,16 +1,20 @@
 """Production training launcher: any assigned arch × shape on the
-production mesh (dry-run lowering) or a reduced config end-to-end on CPU,
-always fed through the disaggregated data service.
+production mesh (dry-run lowering), or a reduced config trained for real on
+this host's devices, always fed through the disaggregated data service.
 
 Two modes:
 
-  --execute      REDUCED config, real training on this host's devices, data
-                 via a local service deployment (workers + dispatcher).
-                 The smoke-scale twin of the production job.
+  --execute      REDUCED config (``scaled_down()``), real training on this
+                 host's default device — the chip on a TPU host, the CPU
+                 elsewhere — with data from a local service deployment
+                 (workers + dispatcher) through ``DeviceFeeder``.  Compiles
+                 go to the persistent cache (``use_compile_cache``).  The
+                 full-width served path on a chip is ``chip_smoke.py``.
   (default)      FULL config, production mesh: lower + compile the sharded
-                 train_step exactly as the multi-pod dry-run does, print the
-                 memory/cost analysis, and exit — the pre-flight a real
-                 cluster launch would run first.
+                 train_step exactly as the multi-pod dry-run does, on 512
+                 CPU devices standing in for the pod, print the memory/cost
+                 analysis, and exit — the pre-flight a real cluster launch
+                 would run first.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b --shape train_4k --seq-shard
@@ -41,8 +45,6 @@ def main() -> None:
 
     # pre-flight: compile the production job (needs 512 host devices BEFORE
     # jax initializes, so re-exec through the dryrun module)
-    from repro.launch import dryrun
-
     cmd = [
         sys.executable, "-m", "repro.launch.dryrun",
         "--arch", args.arch.replace("-", "_").replace(".", "p"),
@@ -77,8 +79,10 @@ def _execute_reduced(args) -> None:
     from repro.models.config import ShapeConfig
     from repro.train import (
         AdamWConfig, init_train_state, make_train_step, save_checkpoint,
+        use_compile_cache,
     )
 
+    use_compile_cache()
     cfg = get_config(args.arch).scaled_down()
     model = build_model(cfg)
     opt = AdamWConfig(lr=1e-3, warmup_steps=5, decay_steps=args.steps)

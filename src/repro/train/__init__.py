@@ -13,6 +13,7 @@ from .step import (
     make_train_step,
 )
 from .checkpoint import latest_step, restore_checkpoint, save_checkpoint
+from .compile_cache import use_compile_cache
 from ..feed import DeviceFeeder, FeedMetrics
 
 __all__ = [
@@ -30,4 +31,5 @@ __all__ = [
     "make_train_step",
     "restore_checkpoint",
     "save_checkpoint",
+    "use_compile_cache",
 ]

@@ -220,3 +220,17 @@ class TestAutoscaler:
         n = scaler.step()
         assert n >= 1
         assert len(orch.live_workers) >= 1
+
+
+class TestRooflinePeaks:
+    def test_v5e_peaks_by_device_kind(self):
+        from repro.launch.roofline import peaks_for
+
+        peak = peaks_for("TPU v5 lite")
+        assert (peak.flops, peak.hbm_bw) == (197e12, 819e9)
+
+    def test_unknown_device_kind_is_an_error(self):
+        from repro.launch.roofline import peaks_for
+
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks_for("cpu")

@@ -40,6 +40,27 @@ class TestDeviceFeeder:
         assert set(seen) <= set(range(32))
         assert len(seen) >= 16
 
+    def test_ring_views_are_borrowed_only_where_device_put_copies(
+        self, service_factory
+    ):
+        """The CPU backend's device_put aliases an aligned host buffer, so
+        a device array made from a borrowed ring view would change when the
+        worker reuses the slot: on the CPU the feeder's client copies each
+        batch out of the ring instead of lending views."""
+        raw = np.zeros(2 * 4096, np.uint8)
+        start = -raw.ctypes.data % 4096  # page-aligned, like a ring slot
+        host = raw[start : start + 4096].view(np.int32)
+        placed = jax.device_put(host)
+        host[:] = 1
+        assert np.asarray(placed).any(), "the CPU backend copied this time"
+        svc = service_factory(num_workers=1, transport="tcp")
+        dds = _ids_pipeline(16).distribute(service=svc, processing_mode="dynamic")
+        with DeviceFeeder(dds) as feeder:
+            seen = [int(v) for b in feeder for v in np.asarray(b["x"])[:, 0]]
+        assert len(seen) == len(set(seen)) and set(seen) <= set(range(16))
+        assert dds.last_client.metrics.shm_batches > 0
+        assert dds.last_client._zero_copy is False
+
     def test_double_buffer_hides_slow_producer(self, service_factory):
         """With a sleep-map producer and a sleeping 'accelerator', the
         feeder overlaps production/transfer with compute: wall time must

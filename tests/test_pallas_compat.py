@@ -1,10 +1,8 @@
 """Drift canary for the Pallas API surface the kernel layer depends on.
 
-The seed's 38 kernel-test failures all traced to ONE renamed symbol
-(``pltpu.TPUCompilerParams`` vs ``pltpu.CompilerParams``) plus follow-on
-convention drift.  This file pins every Pallas name the kernels use so the
-next jax bump fails at a single readable assert — not 38 scattered
-tracebacks — and documents exactly which surface a port must re-verify.
+The repo supports jax 0.9.0 only.  This file pins every Pallas name the
+kernels use so a jax upgrade that renames one fails at a single readable
+assert, and documents exactly which surface a port must re-verify.
 """
 import pytest
 
@@ -20,15 +18,15 @@ from repro.kernels import pallas_compat as compat
 
 class TestCompatShim:
     def test_compiler_params_resolves(self):
-        """One of the two known spellings must exist and accept
-        dimension_semantics — the exact call every kernel makes."""
+        """pltpu.CompilerParams accepts dimension_semantics — the exact
+        call every kernel makes."""
         params = compat.compiler_params(
             dimension_semantics=("parallel", "arbitrary")
         )
         assert params is not None
 
     def test_memory_spaces_exist(self):
-        for name in ("VMEM", "SMEM", "ANY"):
+        for name in ("VMEM", "SMEM"):
             assert getattr(compat, name, None) is not None, name
 
     def test_prefetch_scalar_grid_spec_exists(self):
@@ -41,7 +39,7 @@ class TestPallasCoreSurface:
     @pytest.mark.parametrize(
         "name",
         ["pallas_call", "BlockSpec", "when", "program_id", "num_programs",
-         "cdiv", "dslice"],
+         "cdiv", "dslice", "multiple_of"],
     )
     def test_symbol_exists(self, name):
         assert hasattr(pl, name), (

@@ -122,9 +122,11 @@ class TestSharedServiceE2E:
         pipe = Dataset.range(40).map(lambda x: x * 2).batch(4)
 
         def consume(results, idx):
+            # two jobs (one shared job_name would make the two consumers
+            # split one job's data, and the faster one could take it all)
             dds = pipe.distribute(
                 service=svc, processing_mode="off", sharing=True,
-                job_name="hparam_sweep",
+                job_name=f"hparam_sweep_{idx}",
             )
             results[idx] = [np.asarray(b).tolist() for b in dds]
 
@@ -137,11 +139,12 @@ class TestSharedServiceE2E:
         for t in ts:
             t.join(timeout=60)
         assert set(results) == {0, 1}
-        # both jobs observed valid pipeline output drawn from the shared caches
+        # both jobs read every batch from the shared caches: the window (64)
+        # holds all of each worker's 10 batches, so neither job misses one
         for i in (0, 1):
             vals = [v for b in results[i] for v in np.ravel(b).tolist()]
             assert vals, f"job {i} starved"
-            assert set(vals) <= {2 * x for x in range(40)}
+            assert set(vals) == {2 * x for x in range(40)}
 
     def test_sharing_worker_stats_report_cache(self, service_factory):
         svc = service_factory(num_workers=1, cache_capacity=16)

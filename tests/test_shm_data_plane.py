@@ -278,6 +278,9 @@ class TestProcessPoolExecutor:
         )
         got = sorted(int(v) for e in dds.session() for v in np.ravel(e))
         assert got == sorted(v for i in range(32) for v in [i] * 2)
+        # the degradation is counted, not only logged
+        registry = svc.orchestrator.workers[0].registry
+        assert registry.values()["executor_inthread_fallbacks_total"] > 0
 
     def test_snapshot_byte_identity_across_engines(self, service_factory, tmp_path):
         """worker_processes=0 and =2 materialize byte-identical chunk files
