@@ -224,6 +224,11 @@ class DataServiceClient:
         # ("valid until the next element") instead of copying out — the
         # DeviceFeeder path, where every element is device_put immediately.
         self._zero_copy = zero_copy
+        # ids of decoded elements that borrow a ring slot, and whether the
+        # element last yielded was one of them (its lease goes back to the
+        # worker when the consumer asks for the next element)
+        self._lent_ids: set = set()
+        self.borrowed = False
         self._host_key = host_key or socket.gethostname()
         self.negotiated_compression: Optional[str] = None
         # the dispatcher's autocache verdict for this job, once registered:
@@ -593,6 +598,7 @@ class DataServiceClient:
             return decode_elements(decompress(data))
         if self._zero_copy:
             elems: List[Any] = list(decode_elements(view))
+            self._lent_ids.update(id(e) for e in elems)
             elems.append(_ShmRelease(ring, slot))
             return elems
         try:
@@ -670,6 +676,8 @@ class DataServiceClient:
                     f"likely disagree"
                 ) from item.error
             self.metrics.add(batches=1)
+            self.borrowed = id(item) in self._lent_ids
+            self._lent_ids.discard(id(item))
             yield item
 
     def _iter_coordinated(self) -> Iterator[Element]:

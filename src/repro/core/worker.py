@@ -288,7 +288,9 @@ class _DynamicRunner(_BufferedRunner):
     truth-report through the redelivery queue so the dispatcher's deferred
     task-retirement reclaim resumes the shard at the exact delivered
     position: 0 duplicates, 0 lost, even when the checkpoints sent during a
-    dispatcher outage were dropped.
+    dispatcher outage were dropped.  A shard is reported complete only once
+    its last element has been delivered, never while its tail still sits
+    in the buffer.
     """
 
     CHECKPOINT_EVERY = 64
@@ -304,6 +306,12 @@ class _DynamicRunner(_BufferedRunner):
         # misses a shard another pump just accepted would trick the
         # dispatcher's reconciliation into re-queuing it (duplicates)
         self._shard_lock = threading.Lock()
+        # fully produced shards whose last element is still in the buffer:
+        # shard_id -> that element's offset.  A shard completes only once
+        # its last element is delivered, so a worker lost with the tail in
+        # its buffer leaves the shard to be re-queued, not dropped.
+        self._shard_end: Dict[int, int] = {}
+        self._end_lock = threading.Lock()
         super().__init__(worker, spec, buffer_size)
 
     def _produce(self) -> None:
@@ -410,17 +418,34 @@ class _DynamicRunner(_BufferedRunner):
                         worker_id=wid,
                         offset=self._delivered[sid],
                     )
-            # complete BEFORE dropping from _active_shards: between the two,
-            # another pump's get_shard must still report this shard as held
-            # (a lost completion ack re-enters via _pending_control instead)
-            self._try_call(
-                "complete_shard", job_id=job_id, shard_id=sid, worker_id=wid
-            )
-            self._active_shards.discard(sid)
+            with self._end_lock:
+                delivered = self._delivered[sid] >= offset + produced
+                if not delivered:  # the pop of the last element completes it
+                    self._shard_end[sid] = offset + produced
+            if delivered:
+                self._complete(sid)
+
+    def _complete(self, sid: int) -> None:
+        # complete BEFORE dropping from _active_shards: between the two,
+        # another pump's get_shard must still report this shard as held
+        # (a lost completion ack re-enters via _pending_control instead)
+        self._try_call(
+            "complete_shard",
+            job_id=self._spec["job_id"],
+            shard_id=sid,
+            worker_id=self._worker.worker_id,
+        )
+        self._active_shards.discard(sid)
 
     def _unwrap(self, entry: Any) -> Element:
         elem, sid, off = entry
-        self._delivered[sid] = off  # pops follow production order: monotonic
+        with self._end_lock:
+            self._delivered[sid] = off  # pops follow production order: monotonic
+            last = self._shard_end.get(sid) == off
+            if last:
+                del self._shard_end[sid]
+        if last:
+            self._complete(sid)
         return elem
 
     def get(self, job_id: str, round_index: int, consumer_index: int):

@@ -246,11 +246,10 @@ def _child_main(ctrl_q, out_q) -> None:
 class ProcessPoolExecutor(PipelineExecutor):
     """Run pipelines in ``processes`` forked children with request affinity."""
 
-    def __init__(self, processes: int, registry: Optional[MetricsRegistry] = None):
+    def __init__(self, processes: int, registry: MetricsRegistry):
         import multiprocessing
 
         self.width = max(1, int(processes))
-        registry = registry if registry is not None else MetricsRegistry()
         self._fallbacks = registry.counter(
             "executor_inthread_fallbacks_total",
             "pipelines the process pool ran in the worker's own thread",
@@ -510,9 +509,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                 pass
 
 
-def make_executor(
-    processes: int, registry: Optional[MetricsRegistry] = None
-) -> PipelineExecutor:
+def make_executor(processes: int, registry: MetricsRegistry) -> PipelineExecutor:
     """Build the engine for ``worker_processes=N`` (0/1-thread semantics: 0
     keeps the paper's in-thread engine; N >= 1 runs an N-child pool whose
     fallback counter lives in ``registry``)."""

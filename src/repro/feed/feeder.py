@@ -159,10 +159,11 @@ class DeviceFeeder:
         co-located worker the shm ring's borrowed views feed
         ``jax.device_put`` directly — host batch bytes are copied exactly
         once, shm slot → device.  The lease contract (views valid until
-        the next ``next(it)``) holds because ``_run`` waits until each
-        batch's transfer has completed before fetching the next one:
-        ``device_put`` may return while the copy still reads the host
-        buffer.  The CPU backend's ``device_put`` does not copy an aligned
+        the next ``next(it)``) holds because ``_run`` waits until a
+        borrowed batch's transfer has completed before fetching the next
+        one: ``device_put`` may return while the copy still reads the host
+        buffer.  Owned batches are not waited for, so their copy overlaps
+        the next fetch.  The CPU backend's ``device_put`` does not copy an aligned
         host buffer at all — the device array aliases it, and would change
         when the worker reuses the slot — so on the CPU the client copies
         each batch out of the ring instead.  Remote workers are unaffected
@@ -210,6 +211,9 @@ class DeviceFeeder:
                     )
                 t0 = time.perf_counter()
                 placed = self._to_device(batch)
+                if getattr(self._client, "borrowed", False):
+                    # the next fetch hands the batch's ring slot back
+                    jax.block_until_ready(placed)
                 dt = time.perf_counter() - t0
                 nbytes = leaf_nbytes(batch)
                 self.metrics.add_transfer(dt, nbytes)
@@ -234,8 +238,7 @@ class DeviceFeeder:
             elif self._mesh is not None and self._plan is not None:
                 self._shardings = infer_batch_shardings(batch, self._mesh, self._plan)
             self._shardings_ready = True
-        # wait for the copy: the next fetch hands the batch's ring slot back
-        return jax.block_until_ready(put_batch(batch, self._shardings))
+        return put_batch(batch, self._shardings)
 
     def _put(self, item: Any) -> bool:
         while not self._closed.is_set():

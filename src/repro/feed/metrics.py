@@ -9,7 +9,9 @@ buckets —
   fetch     time its transfer thread spent blocked on the host iterator
             (the data service could not keep up),
   transfer  time spent in ``jax.device_put`` / global-array assembly
-            (host→device bandwidth),
+            (host→device bandwidth); for a batch borrowed from the shm
+            ring this includes waiting for the copy to finish, for an
+            owned batch only the dispatch of the copy,
   compute   time the consumer spent between ``next()`` calls (the train
             step itself),
 

@@ -72,15 +72,15 @@ def _router_kernel(
     for j in range(1, k):
         all_choices = all_choices + onehots[j]  # (Tb, E) ∈ {0,1}
     # exclusive prefix sum over tokens as a strictly-lower-triangular mask
-    # matmul (Mosaic has no cumsum); 0/1 operands and sums <= block_t are
-    # exact in f32
+    # matmul (Mosaic has no cumsum); 0/1 operands are exact in bf16 and
+    # sums <= block_t exact in the f32 accumulator, so the default
+    # (single-pass) precision is exact
     strict = (
         jax.lax.broadcasted_iota(jnp.int32, (Tb, Tb), 0)
         > jax.lax.broadcasted_iota(jnp.int32, (Tb, Tb), 1)
     ).astype(jnp.float32)
     before_tok = jax.lax.dot_general(
         strict, all_choices.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).astype(jnp.int32)
     prior_round = jnp.zeros_like(all_choices)

@@ -121,7 +121,9 @@ class EncDecModel:
         enc_out = self.encode(params, batch["enc_embeds"])
         tokens = batch["tokens"]
         B, S = tokens.shape
-        x = params["embed"].astype(L.cdt(cfg))[tokens]
+        # gather f32 rows, then cast: the backward scatter-add of a frequent
+        # token's rows then accumulates in f32, not bf16
+        x = params["embed"][tokens].astype(L.cdt(cfg))
         x = x + _sinusoid(S, cfg.d_model).astype(x.dtype)[None]
         x = shard_activations(x, "bsd")
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
@@ -189,7 +191,7 @@ class EncDecModel:
         cfg = self.cfg
         pos = cache["pos"]
         B = tokens.shape[0]
-        x = params["embed"].astype(L.cdt(cfg))[tokens][:, None, :]
+        x = params["embed"][tokens].astype(L.cdt(cfg))[:, None, :]
         x = x + _sinusoid_at(pos, cfg.d_model).astype(x.dtype)[None, None, :]
 
         def body(carry, inp):

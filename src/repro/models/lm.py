@@ -210,7 +210,9 @@ class LanguageModel:
         else:
             tokens = batch["tokens"]
             B, S = tokens.shape
-            x = params["embed"].astype(L.cdt(cfg))[tokens]
+            # gather f32 rows, then cast: the backward scatter-add of a
+            # frequent token's rows then accumulates in f32, not bf16
+            x = params["embed"][tokens].astype(L.cdt(cfg))
             positions = batch.get("positions")
             if positions is None:
                 positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
@@ -289,7 +291,7 @@ class LanguageModel:
     ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
         cfg = self.cfg
         pos = cache["pos"]
-        x = params["embed"].astype(L.cdt(cfg))[tokens][:, None, :]  # (B,1,d)
+        x = params["embed"][tokens].astype(L.cdt(cfg))[:, None, :]  # (B,1,d)
         new_cache: Dict[str, Any] = {"pos": pos + 1}
         for gi, g in enumerate(self.groups):
             gp, gc = params[f"group{gi}"], cache[f"group{gi}"]

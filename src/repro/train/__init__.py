@@ -9,6 +9,7 @@ from .step import (
     cross_entropy,
     init_train_state,
     make_eval_step,
+    make_grad_fn,
     make_loss_fn,
     make_train_step,
 )
@@ -27,6 +28,7 @@ __all__ = [
     "latest_step",
     "lr_schedule",
     "make_eval_step",
+    "make_grad_fn",
     "make_loss_fn",
     "make_train_step",
     "restore_checkpoint",

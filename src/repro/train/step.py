@@ -42,6 +42,43 @@ def make_loss_fn(model: Model) -> Callable:
     return loss_fn
 
 
+def make_grad_fn(model: Model, microbatches: int = 1) -> Callable:
+    """Returns grads(params, batch) -> (loss, aux, grads).
+
+    ``microbatches > 1`` accumulates gradients over batch slices in f32
+    (pipeline-friendly; also shrinks activation memory for the biggest
+    configs).
+    """
+    grad_fn = jax.value_and_grad(make_loss_fn(model), has_aux=True)
+
+    def grads_of(params: Any, batch: Dict[str, Any]):
+        if microbatches <= 1:
+            (loss, aux), grads = grad_fn(params, batch)
+            return loss, aux, grads
+
+        def slice_batch(i):
+            return jax.tree.map(
+                lambda x: x.reshape((microbatches, -1) + x.shape[1:])[i], batch
+            )
+
+        def acc_body(carry, i):
+            g_acc, l_acc = carry
+            (l, _aux), g = grad_fn(params, slice_batch(i))
+            g_acc = jax.tree.map(lambda a, b: a + b, g_acc, g)
+            return (g_acc, l_acc + l), None
+
+        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        (grads, loss_sum), _ = lax.scan(
+            acc_body, (g0, jnp.zeros((), jnp.float32)), jnp.arange(microbatches)
+        )
+        grads = jax.tree.map(lambda g: g / microbatches, grads)
+        loss = loss_sum / microbatches
+        aux = {"loss": loss, "z_loss": jnp.zeros(()), "accuracy": jnp.zeros(())}
+        return loss, aux, grads
+
+    return grads_of
+
+
 def make_train_step(
     model: Model,
     opt_cfg: Optional[opt.AdamWConfig] = None,
@@ -49,37 +86,14 @@ def make_train_step(
 ) -> Callable:
     """Returns step(train_state, batch) -> (train_state, metrics).
 
-    train_state = {"params", "opt"}.  ``microbatches > 1`` accumulates
-    gradients over batch slices (pipeline-friendly; also shrinks activation
-    memory for the biggest configs).
+    train_state = {"params", "opt"}; gradients as ``make_grad_fn``.
     """
     opt_cfg = opt_cfg or opt.AdamWConfig()
-    loss_fn = make_loss_fn(model)
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grads_of = make_grad_fn(model, microbatches)
 
     def step(state: Dict[str, Any], batch: Dict[str, Any]):
         params = state["params"]
-        if microbatches <= 1:
-            (loss, aux), grads = grad_fn(params, batch)
-        else:
-            def slice_batch(i):
-                return jax.tree.map(
-                    lambda x: x.reshape((microbatches, -1) + x.shape[1:])[i], batch
-                )
-
-            def acc_body(carry, i):
-                g_acc, l_acc = carry
-                (l, _aux), g = grad_fn(params, slice_batch(i))
-                g_acc = jax.tree.map(lambda a, b: a + b, g_acc, g)
-                return (g_acc, l_acc + l), None
-
-            g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (grads, loss_sum), _ = lax.scan(
-                acc_body, (g0, jnp.zeros((), jnp.float32)), jnp.arange(microbatches)
-            )
-            grads = jax.tree.map(lambda g: g / microbatches, grads)
-            loss = loss_sum / microbatches
-            aux = {"loss": loss, "z_loss": jnp.zeros(()), "accuracy": jnp.zeros(())}
+        loss, aux, grads = grads_of(params, batch)
         new_params, new_opt, om = opt.apply_updates(params, grads, state["opt"], opt_cfg)
         metrics = {**aux, **om, "total_loss": loss}
         return {"params": new_params, "opt": new_opt}, metrics

@@ -3,10 +3,13 @@
 ``chip_smoke.py`` refuses to run without a TPU, but only in ``main()``: the
 served phase itself (service → pool children → shm ring → DeviceFeeder →
 jitted train step) and the checks that judge it are plain functions, so
-they run here on ``scaled_down()`` StarCoder2 with 64-token sequences.
+they run here on ``scaled_down()`` StarCoder2 with 64-token sequences; the
+``--chips 4`` phase runs on four forced CPU devices in a child process.
 """
 import importlib.util
+import json
 import os
+import subprocess
 import sys
 from pathlib import Path
 
@@ -85,3 +88,53 @@ def test_child_death_before_first_element_moves_the_fallback_counter(smoke):
         assert smoke.pool_fallbacks(svc.orchestrator) > 0
     finally:
         svc.orchestrator.stop()
+
+
+_SHARDED = r"""
+import dataclasses, importlib.util, json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+spec = importlib.util.spec_from_file_location("chip_smoke", sys.argv[1])
+smoke = importlib.util.module_from_spec(spec)
+sys.modules["chip_smoke"] = smoke
+spec.loader.exec_module(smoke)
+from repro.configs import get_config
+
+# at this size agreeing leaves differ by up to ~1e-2 and a dropped row
+# moves every leaf by ~0.17 or more: the bound sits between them
+rtol = 4e-2
+cfg = get_config("starcoder2-3b").scaled_down().replace(dtype="bfloat16")
+run = smoke.run_sharded(cfg, devices=jax.devices()[:4], seq_len=64, steps=3)
+smoke.check_sharded(run, rtol)
+faulty = dataclasses.replace(run, leaf_err=run.planted_err)
+try:
+    smoke.check_sharded(faulty, rtol)
+    caught = False
+except smoke.CheckFailed:
+    caught = True
+print(json.dumps({
+    "rows": run.rows_per_device,
+    "worst": max(run.leaf_err.values()),
+    "planted_min": min(run.planted_err.values()),
+    "caught": caught,
+}))
+"""
+
+
+def test_sharded_phase_on_four_cpu_devices_catches_a_dropped_row(tmp_path):
+    """The --chips 4 phase on 4 forced CPU devices with bf16 compute: mesh
+    gradients match the one-device microbatched ones leaf by leaf, and the
+    same comparison fails when one batch row is dropped on the mesh.  Its
+    own process: XLA_FLAGS must be set before jax initializes."""
+    script = tmp_path / "sharded.py"
+    script.write_text(_SHARDED)
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu")
+    res = subprocess.run(
+        [sys.executable, str(script), str(ROOT / "chip_smoke.py")],
+        capture_output=True, text=True, timeout=300, env=env,
+    )
+    assert res.returncode == 0, res.stderr[-4000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["rows"] == [[1, 1, 1, 1]] * 3
+    assert out["worst"] < out["planted_min"] / 4
+    assert out["caught"]

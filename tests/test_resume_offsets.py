@@ -7,6 +7,8 @@ Practical guarantee: NO LOSS; duplicates bounded by the checkpoint window
 (elements yielded after the last offset checkpoint are re-produced by the
 replacement worker) — at-least-once within the window, exactly-once at
 window granularity."""
+import time
+
 import numpy as np
 
 from repro.core import ShardingPolicy, VisitationGuarantee, guarantee_for
@@ -95,3 +97,26 @@ class TestServiceResumeE2E:
         # duplicates bounded by one checkpoint window per lost shard
         dupes = len(got) - len(set(got))
         assert dupes <= _DynamicRunner.CHECKPOINT_EVERY * 3
+
+    def test_shard_completes_only_once_its_tail_is_delivered(self, service_factory):
+        """A shard whose last elements still sit in the worker's buffer is
+        not complete yet: a worker lost at that moment leaves the shard to
+        be re-queued at its offset, not dropped with its tail."""
+        svc = service_factory(
+            num_workers=1, heartbeat_timeout=0.5, gc_interval=0.1,
+            worker_buffer_size=64,
+        )
+        ds = Dataset.range(32).batch(1).distribute(
+            service=svc, processing_mode="dynamic", resume_offsets=True,
+            buffer_size=1, fetch_window=1, max_batch=1,
+        )
+        it = iter(ds)
+        got = [int(np.asarray(next(it)).ravel()[0])]
+        time.sleep(0.5)  # the worker produces every shard into its buffer
+        job = next(iter(svc.orchestrator.stats()["jobs"].values()))
+        assert job["shards"]["total"] > 1
+        assert job["shards"]["completed"] == 0
+        svc.orchestrator.kill_worker(0)
+        svc.orchestrator.add_worker()
+        got += [int(np.asarray(b).ravel()[0]) for b in it]
+        assert set(got) == set(range(32))

@@ -165,6 +165,22 @@ class TestShmNegotiation:
         assert sess.metrics.shm_tasks > 0, "co-located tcp worker must offer shm"
         assert sess.metrics.shm_batches > 0
 
+    @pytest.mark.parametrize(
+        "shm,zero_copy", [(True, True), (True, False), (False, True)]
+    )
+    def test_borrowed_marks_ring_views(self, service_factory, shm, zero_copy):
+        """``borrowed`` is True exactly for elements that are views into a
+        ring slot: those the DeviceFeeder must finish copying before it
+        asks for the next element."""
+        svc = service_factory(num_workers=1, transport="tcp")
+        dds = _graph_ds().distribute(
+            service=svc, processing_mode="dynamic", compression=None, max_batch=8
+        )
+        sess = dds.session(shm=shm, zero_copy=zero_copy)
+        flags = [sess.borrowed for _ in sess]
+        assert len(flags) == 64
+        assert any(flags) == (shm and zero_copy)
+
     def test_shm_false_stays_inline(self, service_factory):
         svc = service_factory(num_workers=1, transport="tcp")
         dds = _graph_ds().distribute(
