@@ -1,0 +1,10 @@
+"""Share, in percent, of the traced window in which no operation ran on the
+device: 1 minus the union of the device's op intervals over the window,
+averaged over the chips (``bench/trace.py``)."""
+
+
+def read(run):
+    t = run["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
