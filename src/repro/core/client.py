@@ -469,15 +469,18 @@ class DataServiceClient:
         while not self._closed.is_set() and not handle.done and not handle.failed:
             # per-element-batch sampling decision: unsampled fetches carry
             # no trace key at all, keeping the hot-path payload unchanged
-            ctx = (
-                self.trace_root.child()
+            root = (
+                self.trace_root
                 if self.trace_root is not None and self.tracer.should_sample()
                 else None
             )
             try:
-                wall = time.time() if ctx is not None else 0.0
                 t0 = time.perf_counter()
-                try:
+                # the span is recorded even on failure: the worker may have
+                # recorded children before the response was lost
+                with self.tracer.span(
+                    "client.fetch", root, task_id=handle.task_id
+                ) as ctx:
                     kw: Dict[str, Any] = dict(
                         task_id=handle.task_id, job_id=self._job_id
                     )
@@ -494,18 +497,6 @@ class DataServiceClient:
                         )
                     else:
                         resp = stub.call("get_element", **kw)
-                finally:
-                    # span recorded even on failure: the worker may have
-                    # recorded children before the response was lost
-                    if ctx is not None:
-                        self.tracer.record(
-                            "client.fetch",
-                            ctx,
-                            wall,
-                            time.perf_counter() - t0,
-                            parent_id=self.trace_root.span_id,
-                            task_id=handle.task_id,
-                        )
                 self.metrics.add(
                     fetch_time=time.perf_counter() - t0, rpcs=1
                 )
@@ -607,12 +598,18 @@ class DataServiceClient:
             ring.release(slot)
 
     def _enqueue(self, elem: Element) -> None:
-        while not self._closed.is_set():
-            try:
-                self._queue.put(elem, timeout=0.1)
-                return
-            except queue.Full:
-                continue
+        try:
+            self._queue.put_nowait(elem)
+            return
+        except queue.Full:
+            pass
+        with self.tracer.span("client.enqueue", None):  # blocked: queue full
+            while not self._closed.is_set():
+                try:
+                    self._queue.put(elem, timeout=0.1)
+                    return
+                except queue.Full:
+                    continue
 
     def _maybe_finish(self) -> None:
         with self._tasks_lock:
@@ -696,8 +693,8 @@ class DataServiceClient:
                 time.sleep(0.02)
                 continue
             handle = live[round_index % len(live)]
-            ctx = (
-                self.trace_root.child()
+            root = (
+                self.trace_root
                 if self.trace_root is not None and self.tracer.should_sample()
                 else None
             )
@@ -707,28 +704,20 @@ class DataServiceClient:
                 round_index=round_index,
                 consumer_index=self._consumer_index,
             )
-            if ctx is not None:
-                kw["trace"] = ctx.to_wire()
-            wall = time.time() if ctx is not None else 0.0
             t0 = time.perf_counter()
-            try:
-                resp = handle.stub.call("get_element", **kw)
-                self.metrics.add(rpcs=1)
-            except TransportError:
-                handle.failed = True
-                continue
-            finally:
-                self.metrics.add(stall_time=time.perf_counter() - t0)
+            with self.tracer.span(
+                "client.fetch", root, task_id=handle.task_id, round_index=round_index
+            ) as ctx:
                 if ctx is not None:
-                    self.tracer.record(
-                        "client.fetch",
-                        ctx,
-                        wall,
-                        time.perf_counter() - t0,
-                        parent_id=self.trace_root.span_id,
-                        task_id=handle.task_id,
-                        round_index=round_index,
-                    )
+                    kw["trace"] = ctx.to_wire()
+                try:
+                    resp = handle.stub.call("get_element", **kw)
+                    self.metrics.add(rpcs=1)
+                except TransportError:
+                    handle.failed = True
+                    continue
+                finally:
+                    self.metrics.add(stall_time=time.perf_counter() - t0)
             status = resp["status"]
             if status == FetchStatus.OK.value:
                 self.metrics.add(batches=1)

@@ -190,7 +190,6 @@ class ControlPlaneMixin:
             "idle_s_per_step": mean("idle_s_per_step"),
             "fetch_s_per_step": mean("fetch_s_per_step"),
             "transfer_s_per_step": mean("transfer_s_per_step"),
-            "queue_depth": mean("queue_depth"),
         }
 
     def _apply_job(self, p: Dict[str, Any]) -> _Job:

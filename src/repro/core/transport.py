@@ -60,6 +60,7 @@ from typing import Any, Callable, Dict, Optional, Protocol
 # Re-exported for backwards compatibility: payload compression used to live
 # here; it is now a pluggable registry (see codecs.py for negotiation rules).
 from .codecs import compress, decompress  # noqa: F401
+from ..obs.tracing import annotate
 
 # Default per-call deadline when a Stub is built without an explicit
 # timeout.  Paths whose liveness budget is tighter than this (standby
@@ -202,15 +203,23 @@ class _InprocConnection:
 # ---------------------------------------------------------------------------
 # TCP transport (length-prefixed pickle; request/response per connection pool)
 # ---------------------------------------------------------------------------
-def _send_msg(sock: socket.socket, obj: Any) -> None:
-    data = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-    sock.sendall(struct.pack("<I", len(data)) + data)
+def _send_msg(sock: socket.socket, obj: Any, method: str = "") -> None:
+    """One length-prefixed pickle frame.  ``method`` names the RPC on the
+    spans (the response to a ``get_elements`` carries the batch)."""
+    with annotate("transport.encode", method=method):
+        data = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
+        frame = struct.pack("<I", len(data)) + data
+    with annotate("transport.send", method=method):
+        sock.sendall(frame)
 
 
-def _recv_msg(sock: socket.socket) -> Any:
-    hdr = _recv_exact(sock, 4)
+def _recv_msg(sock: socket.socket, method: str = "") -> Any:
+    hdr = _recv_exact(sock, 4)  # the wait for a frame: no span
     (n,) = struct.unpack("<I", hdr)
-    return pickle.loads(_recv_exact(sock, n))
+    with annotate("transport.recv", method=method):
+        data = _recv_exact(sock, n)
+    with annotate("transport.decode", method=method):
+        return pickle.loads(data)
 
 
 def _recv_exact(sock: socket.socket, n: int) -> bytes:
@@ -239,9 +248,9 @@ class TCPServer:
                         return
                     try:
                         result = outer._handler.handle(method, payload)
-                        _send_msg(self.request, ("ok", result))
+                        _send_msg(self.request, ("ok", result), method)
                     except Exception as e:  # ship the error to the caller
-                        _send_msg(self.request, ("err", repr(e)))
+                        _send_msg(self.request, ("err", repr(e)), method)
 
         class _Server(socketserver.ThreadingTCPServer):
             daemon_threads = True
@@ -270,8 +279,8 @@ class _TCPConnection:
 
     def call(self, method: str, payload: Dict[str, Any]) -> Dict[str, Any]:
         with self._lock:
-            _send_msg(self._sock, (method, payload))
-            status, result = _recv_msg(self._sock)
+            _send_msg(self._sock, (method, payload), method)
+            status, result = _recv_msg(self._sock, method)
         if status != "ok":
             raise TransportError(f"remote error from {method}: {result}")
         return result

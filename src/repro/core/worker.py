@@ -782,6 +782,12 @@ class Worker:
         self.registry = MetricsRegistry()
         self.metrics = WorkerMetrics(self.registry)
         self.tracer = Tracer(process=f"worker:{self.worker_id}")
+        # fetches that asked for the shm ring and went inline, by reason
+        self._shm_inline = self.registry.counter(
+            "worker_shm_inline_total",
+            "shm-channel fetches answered inline: ring_full, too_large, "
+            "no_channel or error",
+        )
         # worker_processes=0 keeps the paper's in-thread engine; N>=1 runs
         # pipelines in a pool of N forked children (data.executors)
         self._executor = make_executor(worker_processes, self.registry)
@@ -1175,9 +1181,11 @@ class Worker:
         with self._lock:
             ring = self._shm_channels.get(channel)
         if ring is None:
+            self._shm_inline.labels(reason="no_channel").inc()
             return False
         slot = ring.try_acquire()
         if slot is None:  # ring full: consumer behind (or leases lost)
+            self._shm_inline.labels(reason="ring_full").inc()
             return False
         try:
             view = ring.slot_view(slot)
@@ -1196,11 +1204,13 @@ class Worker:
         except FrameTooLarge:
             ring.cancel(slot)
             out.pop("shm_codec", None)
+            self._shm_inline.labels(reason="too_large").inc()
             return False
         except Exception as e:  # never poison the fetch path: go inline
             ring.cancel(slot)
             out.pop("shm_codec", None)
             self._note_error("shm serve", e)
+            self._shm_inline.labels(reason="error").inc()
             return False
         out["shm_slot"] = slot
         out["shm_len"] = length
@@ -1244,9 +1254,11 @@ class Worker:
             spec = self._task_specs.get(task_id)
         if runner is None:
             return {"status": FetchStatus.PENDING.value, "count": 0}
-        status, elems = runner.get_many(
-            job_id, max(1, int(max_batch)), timeout=min(1.0, float(timeout))
-        )
+        # the long-poll: until the first element is there or the poll ends
+        with self.tracer.span("worker.wait", sctx):
+            status, elems = runner.get_many(
+                job_id, max(1, int(max_batch)), timeout=min(1.0, float(timeout))
+            )
         out: Dict[str, Any] = {"status": status.value, "count": len(elems)}
         nbytes = 0
         if elems:
@@ -1254,32 +1266,25 @@ class Worker:
             self.metrics.add(batches_served=len(elems), bytes_served=nbytes)
             out["nbytes"] = nbytes
             compression = spec.get("compression") if spec else None
-            if shm_channel and self._shm_serve(
-                out, shm_channel, elems, compression
-            ):
+            in_ring = False
+            if shm_channel:
+                with self.tracer.span("worker.encode", sctx, nbytes=nbytes, path="shm"):
+                    in_ring = self._shm_serve(out, shm_channel, elems, compression)
+            if in_ring:
                 pass  # descriptor is in `out`; nothing travels inline
             elif compression:
-                e0 = time.perf_counter()
-                encoded = encode_elements(elems)
-                try:
-                    frame = compress(encoded, compression)
-                except ValueError:
-                    # the negotiated codec is not in THIS worker's registry
-                    # (heterogeneous pool): ship uncompressed rather than
-                    # fail every fetch — frames are tag-prefixed, so the
-                    # client decodes either way.
-                    frame = compress(encoded, None)
-                if sctx is not None:
-                    dur = time.perf_counter() - e0
-                    self.tracer.record(
-                        "worker.encode",
-                        sctx.child(),
-                        time.time() - dur,
-                        dur,
-                        parent_id=sctx.span_id,
-                        nbytes=nbytes,
-                        codec=compression,
-                    )
+                with self.tracer.span(
+                    "worker.encode", sctx, nbytes=nbytes, codec=compression
+                ):
+                    encoded = encode_elements(elems)
+                    try:
+                        frame = compress(encoded, compression)
+                    except ValueError:
+                        # the negotiated codec is not in THIS worker's
+                        # registry (heterogeneous pool): ship uncompressed
+                        # rather than fail every fetch — frames are
+                        # tag-prefixed, so the client decodes either way.
+                        frame = compress(encoded, None)
                 out["batch_compressed"] = frame
             else:
                 out["elements"] = elems

@@ -21,10 +21,12 @@ Invariants both engines honour:
   ``offset + 1``; skipping for resume happens at the source (child side
   for the pool — skipped elements never cross the IPC boundary).
 * **Observability flows back** — children ship cumulative per-op stats
-  snapshots which the parent folds into the request's own ``ExecContext``,
-  so stall attribution, ``metrics_dump`` and ``trace_dump`` see pooled
-  pipelines exactly like in-thread ones.  Parent-side knob writes (e.g. an
-  autotuner adjusting parallelism) are forwarded to the owning child.
+  snapshots every ``STATS_INTERVAL_S``, also while they wait for credit,
+  and the parent's router thread folds each into the request's own
+  ``ExecContext`` as it arrives, so stall attribution, ``metrics_dump`` and
+  ``trace_dump`` see pooled pipelines exactly like in-thread ones.
+  Parent-side knob writes (e.g. an autotuner adjusting parallelism) are
+  forwarded to the owning child.
 
 Failure contract: a child that dies or errors *before yielding anything*
 triggers a transparent in-thread retry (covers graphs that capture
@@ -55,6 +57,7 @@ import zlib
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from ..obs.registry import MetricsRegistry
+from ..obs.tracing import annotate
 from .iterators import ExecContext, Knob, build_iterator
 
 logger = logging.getLogger(__name__)
@@ -148,6 +151,14 @@ def _run_request(req: _ChildRequest, graph_blob, seed, offset, default_par, out_
     req.ctx = ctx
     sent = 0
     last_stats = time.monotonic()
+
+    def stats_due() -> None:
+        nonlocal last_stats
+        now = time.monotonic()
+        if now - last_stats >= STATS_INTERVAL_S:
+            out_q.put(("stats", req.rid, _stats_snapshot(ctx)))
+            last_stats = now
+
     try:
         graph = pickle.loads(graph_blob)
         for i, elem in enumerate(build_iterator(graph, ctx)):
@@ -155,18 +166,18 @@ def _run_request(req: _ChildRequest, graph_blob, seed, offset, default_par, out_
                 break
             if i < offset:
                 continue
-            # block on flow-control credit, staying responsive to cancel
+            # block on flow-control credit, staying responsive to cancel;
+            # the op stats still go out on their timer, so a child the
+            # consumer holds back is not silent
             while not req.credits.acquire(timeout=0.1):
                 if req.stop.is_set():
                     break
+                stats_due()
             if req.stop.is_set():
                 break
             out_q.put(("elem", req.rid, i + 1, elem))
             sent += 1
-            now = time.monotonic()
-            if now - last_stats >= STATS_INTERVAL_S:
-                out_q.put(("stats", req.rid, _stats_snapshot(ctx)))
-                last_stats = now
+            stats_due()
     except Exception as e:  # ship the failure; the parent decides policy
         try:
             out_q.put(("stats", req.rid, _stats_snapshot(ctx)))
@@ -243,6 +254,19 @@ def _child_main(ctrl_q, out_q) -> None:
 # ---------------------------------------------------------------------------
 # Parent side
 # ---------------------------------------------------------------------------
+class _PoolRequest:
+    """Parent-side state of one pipeline running in a child."""
+
+    __slots__ = ("child", "inq", "ctx", "ctrl", "last_knob")
+
+    def __init__(self, child: int, ctx: ExecContext, ctrl: Any):
+        self.child = child
+        self.inq: "queue.Queue[Any]" = queue.Queue()  # elements and ends
+        self.ctx = ctx
+        self.ctrl = ctrl
+        self.last_knob: Dict[Tuple[int, str], int] = {}
+
+
 class ProcessPoolExecutor(PipelineExecutor):
     """Run pipelines in ``processes`` forked children with request affinity."""
 
@@ -259,10 +283,9 @@ class ProcessPoolExecutor(PipelineExecutor):
         self._ctrl: List[Optional[Any]] = [None] * self.width
         self._out: List[Optional[Any]] = [None] * self.width
         self._lock = threading.Lock()
-        # rid -> (child_index, parent-side delivery queue); plain dict reads
-        # from the router threads are GIL-safe
-        self._pending: Dict[str, Tuple[int, "queue.Queue[Any]"]] = {}
-        self._last_knob: Dict[str, Dict[Tuple[int, str], int]] = {}
+        # rid -> its request; plain dict reads from the router threads are
+        # GIL-safe
+        self._pending: Dict[str, _PoolRequest] = {}
         self._rid_counter = itertools.count()
         self._stopping = threading.Event()
         self._inthread = InThreadExecutor()
@@ -295,31 +318,37 @@ class ProcessPoolExecutor(PipelineExecutor):
             return ctrl, proc
 
     def _route(self, i: int, proc, out_q) -> None:
-        """Demultiplex one child's output queue to per-request queues."""
+        """Demultiplex one child's output queue: op stats are applied here,
+        as they arrive, even while the request's consumer is not pulling;
+        everything else goes to the request's queue."""
         while not self._stopping.is_set():
-            try:
-                msg = out_q.get(timeout=0.2)
-            except queue.Empty:
+            # wait outside the span: executor.recv is the pipe read and the
+            # unpickling of one message (mp.Queue has no public blocking poll)
+            if not out_q._reader.poll(0.2):
                 if proc.is_alive():
                     continue
                 # child died: poison every request routed to it, then exit
                 with self._lock:
-                    victims = [
-                        q for rid, (ci, q) in self._pending.items() if ci == i
-                    ]
+                    victims = [r.inq for r in self._pending.values() if r.child == i]
                 for q in victims:
                     q.put(("died",))
                 return
-            q = None
-            entry = self._pending.get(msg[1])
-            if entry is not None:
-                q = entry[1]
-            if q is not None:
-                q.put(msg)
+            try:
+                with annotate("executor.recv"):
+                    msg = out_q.get(timeout=0.2)
+            except queue.Empty:
+                continue
+            req = self._pending.get(msg[1])
+            if req is None:
+                continue
+            if msg[0] == "stats":
+                self._apply_stats(req, msg[1], msg[2])
+            else:
+                req.inq.put(msg)
 
     # -- stats / knob plumbing ----------------------------------------------
-    def _apply_stats(self, ctx: ExecContext, snap, rid: str, ctrl) -> None:
-        last = self._last_knob.setdefault(rid, {})
+    def _apply_stats(self, req: _PoolRequest, rid: str, snap) -> None:
+        ctx, last = req.ctx, req.last_knob
         for idx, s in snap.items():
             st = ctx.stat(idx, s["name"])
             st.elements = s["elements"]
@@ -344,7 +373,7 @@ class ProcessPoolExecutor(PipelineExecutor):
                     # the parent side moved the knob (autotuner): forward to
                     # the owning child instead of clobbering the new value
                     try:
-                        ctrl.put(("knob", rid, idx, kind, knob.get()))
+                        req.ctrl.put(("knob", rid, idx, kind, knob.get()))
                     except Exception:
                         pass
                     last[(idx, kind)] = knob.get()
@@ -390,9 +419,10 @@ class ProcessPoolExecutor(PipelineExecutor):
             yield from self._fallback(graph, ctx, affinity, offset)
             return
 
-        inq: "queue.Queue[Any]" = queue.Queue()
+        req = _PoolRequest(child_idx, ctx, ctrl)
+        inq = req.inq
         with self._lock:
-            self._pending[rid] = (child_idx, inq)
+            self._pending[rid] = req
         started = False
         yielded = 0
         uncredited = 0
@@ -437,8 +467,6 @@ class ProcessPoolExecutor(PipelineExecutor):
                         except Exception:
                             pass
                         uncredited = 0
-                elif kind == "stats":
-                    self._apply_stats(ctx, msg[2], rid, ctrl)
                 elif kind == "end":
                     return
                 elif kind == "err":
@@ -469,7 +497,6 @@ class ProcessPoolExecutor(PipelineExecutor):
         finally:
             with self._lock:
                 self._pending.pop(rid, None)
-                self._last_knob.pop(rid, None)
             if started:
                 try:
                     ctrl.put(("cancel", rid))

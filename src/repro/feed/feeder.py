@@ -140,7 +140,6 @@ class DeviceFeeder:
         self._last_report = time.perf_counter()
 
         self._queue: "queue.Queue[Any]" = queue.Queue(maxsize=max(1, depth))
-        self._depth = max(1, depth)
         self._closed = threading.Event()
         self._last_return: Optional[float] = None
         self._client = self._make_session()
@@ -185,43 +184,31 @@ class DeviceFeeder:
     # ------------------------------------------------------------------
     def _run(self) -> None:
         # The service client owns the job's trace context; the feeder's
-        # spans (fetch / device_put) parent onto the same root so one
-        # Perfetto track shows client->dispatcher->worker->feeder.
-        tracer = getattr(self._client, "tracer", None)
-        root = getattr(self._client, "trace_root", None)
+        # sampled spans (fetch / device_put) parent onto the same root so
+        # one Perfetto track shows client->dispatcher->worker->feeder.  The
+        # root is minted when iteration registers the job.
+        tracer = self._client.tracer
         try:
             it = iter(self._client)
             while not self._closed.is_set():
+                root = self._client.trace_root
+                if root is not None and not tracer.should_sample():
+                    root = None
                 t0 = time.perf_counter()
                 try:
-                    batch = next(it)
+                    with tracer.span("feed.fetch", root):
+                        batch = next(it)
                 except StopIteration:
                     break
-                dt = time.perf_counter() - t0
-                self.metrics.add_fetch(dt)
-                sampled = (
-                    tracer is not None
-                    and root is not None
-                    and tracer.should_sample()
-                )
-                if sampled:
-                    tracer.record(
-                        "feed.fetch", root.child(), time.time() - dt, dt,
-                        parent_id=root.span_id,
-                    )
-                t0 = time.perf_counter()
-                placed = self._to_device(batch)
-                if getattr(self._client, "borrowed", False):
-                    # the next fetch hands the batch's ring slot back
-                    jax.block_until_ready(placed)
-                dt = time.perf_counter() - t0
+                self.metrics.add_fetch(time.perf_counter() - t0)
                 nbytes = leaf_nbytes(batch)
-                self.metrics.add_transfer(dt, nbytes)
-                if sampled:
-                    tracer.record(
-                        "feed.device_put", root.child(), time.time() - dt, dt,
-                        parent_id=root.span_id, nbytes=nbytes,
-                    )
+                t0 = time.perf_counter()
+                with tracer.span("feed.device_put", root, nbytes=nbytes):
+                    placed = self._to_device(batch)
+                    if getattr(self._client, "borrowed", False):
+                        # the next fetch hands the batch's ring slot back
+                        jax.block_until_ready(placed)
+                self.metrics.add_transfer(time.perf_counter() - t0, nbytes)
                 if not self._put(placed):
                     return  # closed while the queue was full
                 self._maybe_report()
@@ -241,12 +228,18 @@ class DeviceFeeder:
         return put_batch(batch, self._shardings)
 
     def _put(self, item: Any) -> bool:
-        while not self._closed.is_set():
-            try:
-                self._queue.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
+        try:
+            self._queue.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with self._client.tracer.span("feed.queue_put", None):  # queue full
+            while not self._closed.is_set():
+                try:
+                    self._queue.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     # ------------------------------------------------------------------
@@ -279,33 +272,30 @@ class DeviceFeeder:
         or host→device transfer) is the bottleneck, and the reported stall
         window tells the autoscaler which.
         """
-        t0 = time.perf_counter()
-        compute = None if self._last_return is None else t0 - self._last_return
-        deadline = None if timeout is None else t0 + timeout
-        while True:
-            if self._closed.is_set():
-                raise StopIteration("feeder closed")
-            try:
-                item = self._queue.get(timeout=0.1)
-                break
-            except queue.Empty:
-                if deadline is not None and time.perf_counter() > deadline:
-                    raise TimeoutError(
-                        f"no batch after {timeout:.1f}s (service stalled?)"
-                    )
-        now = time.perf_counter()
-        if item is self._END:
-            self._queue.put(self._END)  # idempotent end for later calls
-            raise StopIteration
-        if isinstance(item, _FeedError):
-            raise RuntimeError("device feed failed") from item.error
-        self.metrics.add_step(
-            idle=now - t0,
-            compute=compute,
-            depth_frac=self._queue.qsize() / self._depth,
-        )
-        self._last_return = time.perf_counter()
-        return item
+        with self._client.tracer.span("feed.next", None):
+            t0 = time.perf_counter()
+            compute = None if self._last_return is None else t0 - self._last_return
+            deadline = None if timeout is None else t0 + timeout
+            while True:
+                if self._closed.is_set():
+                    raise StopIteration("feeder closed")
+                try:
+                    item = self._queue.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    if deadline is not None and time.perf_counter() > deadline:
+                        raise TimeoutError(
+                            f"no batch after {timeout:.1f}s (service stalled?)"
+                        )
+            now = time.perf_counter()
+            if item is self._END:
+                self._queue.put(self._END)  # idempotent end for later calls
+                raise StopIteration
+            if isinstance(item, _FeedError):
+                raise RuntimeError("device feed failed") from item.error
+            self.metrics.add_step(idle=now - t0, compute=compute)
+            self._last_return = time.perf_counter()
+            return item
 
     def __iter__(self) -> Iterator[Any]:
         while True:

@@ -52,7 +52,6 @@ class FeedMetrics:
     transfer_s: float = 0.0  # host->device placement time
     compute_s: float = 0.0  # consumer time between next() calls
     bytes_to_device: int = 0
-    queue_depth_ema: float = 0.0  # device-queue fill observed at next()
     registry: Optional[MetricsRegistry] = field(default=None, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
@@ -79,9 +78,6 @@ class FeedMetrics:
             "bytes_to_device": self.registry.counter(
                 "feed_bytes_to_device", "bytes placed on device"
             ),
-            "queue_depth_ema": self.registry.gauge(
-                "feed_queue_depth", "device-queue fill EMA observed at next()"
-            ),
         }
 
     # -- writers (thread-safe) -------------------------------------------
@@ -99,19 +95,16 @@ class FeedMetrics:
         self._series["transfer_s"].add(seconds)
         self._series["bytes_to_device"].add(nbytes)
 
-    def add_step(self, idle: float, compute: Optional[float], depth_frac: float) -> None:
+    def add_step(self, idle: float, compute: Optional[float]) -> None:
         with self._lock:
             self.steps += 1
             self.idle_s += idle
             if compute is not None:
                 self.compute_s += compute
-            self.queue_depth_ema = 0.8 * self.queue_depth_ema + 0.2 * depth_frac
-            depth_ema = self.queue_depth_ema
         self._series["steps"].inc()
         self._series["idle_s"].add(idle)
         if compute is not None:
             self._series["compute_s"].add(compute)
-        self._series["queue_depth_ema"].set(depth_ema)
 
     # -- derived ----------------------------------------------------------
     @property
@@ -148,7 +141,6 @@ class FeedMetrics:
                 "transfer_s": self.transfer_s,
                 "compute_s": self.compute_s,
                 "bytes_to_device": self.bytes_to_device,
-                "queue_depth_ema": self.queue_depth_ema,
             }
         out["breakdown"] = self.breakdown()
         return out
@@ -182,7 +174,6 @@ class StallWindow:
             d_compute = m.compute_s - self._compute
             d_fetch = m.fetch_s - self._fetch
             d_transfer = m.transfer_s - self._transfer
-            depth = m.queue_depth_ema
             self._steps, self._idle = m.steps, m.idle_s
             self._compute, self._fetch = m.compute_s, m.fetch_s
             self._transfer = m.transfer_s
@@ -192,6 +183,5 @@ class StallWindow:
             "idle_s_per_step": d_idle / d_steps,
             "fetch_s_per_step": d_fetch / d_steps,
             "transfer_s_per_step": d_transfer / d_steps,
-            "queue_depth": depth,
             "steps": float(d_steps),
         }

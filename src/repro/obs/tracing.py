@@ -24,19 +24,54 @@ Span timestamps are wall-clock (``time.time``) ON PURPOSE: they must be
 comparable across processes in one exported trace, which is exactly the
 cross-process exception to this repo's perf_counter-for-intervals rule.
 Durations are still measured with ``perf_counter`` by the callers.
+
+Every :meth:`Tracer.span` also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, sampled or not, so a ``jax.profiler`` session in the
+trainer's process puts the service's own spans (worker long-poll, transport,
+client, feeder) on the device trace's clock.  Outside a profiler session,
+and in a process that never imported JAX (a remote worker), the span pays
+a ``nullcontext`` in its place.  ``span(name, None)`` is the
+context-free form: annotated, never recorded.  :func:`annotate` is the same
+annotation for code that holds no tracer (the transport's framing).
 """
 from __future__ import annotations
 
 import random
+import sys
 import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
+from contextlib import nullcontext
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, ContextManager, Dict, List, Optional
 
-__all__ = ["TraceContext", "Span", "Tracer"]
+__all__ = ["TraceContext", "Span", "Tracer", "annotate"]
+
+_NULL = nullcontext()
+_annotation_cls: Any = None
+
+
+def _annotation(name: str, attrs: Dict[str, Any]) -> ContextManager[Any]:
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        # never import JAX here: a process without it keeps its start-up
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return _NULL
+        _annotation_cls = cls
+    if not cls.is_enabled():  # no profiler session: nothing to annotate
+        return _NULL
+    return cls(name, **attrs)
+
+
+def annotate(name: str, **attrs: Any) -> ContextManager[Any]:
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` with ``attrs`` as
+    its metadata while this process runs a profiler session, else a
+    ``nullcontext``: the profiler half of :meth:`Tracer.span`."""
+    return _annotation(name, attrs)
 
 
 def _new_id(nbytes: int = 8) -> str:
@@ -102,6 +137,45 @@ class Span:
         }
 
 
+class _SpanScope:
+    """The with-block of one :meth:`Tracer.span` (a class, not a generator:
+    the unsampled arm runs on every batch)."""
+
+    __slots__ = ("_tracer", "_name", "_ctx", "_attrs", "_ann", "_child", "_wall", "_t0")
+
+    def __init__(self, tracer: "Tracer", name: str, ctx: Optional[TraceContext],
+                 attrs: Dict[str, Any]):
+        self._tracer = tracer
+        self._name = name
+        self._ctx = ctx
+        self._attrs = attrs
+        self._ann = _annotation(name, attrs)
+
+    def __enter__(self) -> Optional[TraceContext]:
+        self._ann.__enter__()
+        ctx = self._ctx
+        if ctx is None:
+            return None
+        self._child = ctx.child()
+        self._wall = time.time()  # cross-process timestamp (see module docstring)
+        self._t0 = time.perf_counter()
+        return self._child
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        try:
+            if self._ctx is not None:
+                self._tracer.record(
+                    self._name,
+                    self._child,
+                    self._wall,
+                    time.perf_counter() - self._t0,
+                    parent_id=self._ctx.span_id,
+                    **self._attrs,
+                )
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
+
+
 class Tracer:
     """Per-process span recorder with a bounded ring buffer.
 
@@ -162,30 +236,14 @@ class Tracer:
                 self.dropped += 1
             self._spans.append(span)
 
-    @contextmanager
     def span(
         self, name: str, ctx: Optional[TraceContext], **attrs: Any
-    ) -> Iterator[Optional[TraceContext]]:
-        """Record a child span of ``ctx`` around the with-block.  With
-        ``ctx is None`` (tracing off / unsampled) the block runs untimed —
-        the no-op arm costs one None check."""
-        if ctx is None:
-            yield None
-            return
-        child = ctx.child()
-        wall = time.time()  # cross-process timestamp (see module docstring)
-        t0 = time.perf_counter()
-        try:
-            yield child
-        finally:
-            self.record(
-                name,
-                child,
-                wall,
-                time.perf_counter() - t0,
-                parent_id=ctx.span_id,
-                **attrs,
-            )
+    ) -> ContextManager[Optional[TraceContext]]:
+        """Annotate the with-block for the profiler and, with a context,
+        record it as a child span of ``ctx`` (the block gets the child).
+        With ``ctx is None`` (tracing off / unsampled) nothing is recorded
+        and the block gets None; the annotation alone stays."""
+        return _SpanScope(self, name, ctx, attrs)
 
     # -- draining ---------------------------------------------------------
     def drain(self, max_spans: int = 0) -> List[Dict[str, Any]]:

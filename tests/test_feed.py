@@ -250,8 +250,8 @@ class TestFeedMetrics:
         m = FeedMetrics()
         m.add_fetch(0.2)
         m.add_transfer(0.1, 1024)
-        m.add_step(idle=0.3, compute=None, depth_frac=0.5)
-        m.add_step(idle=0.1, compute=0.1, depth_frac=0.5)
+        m.add_step(idle=0.3, compute=None)
+        m.add_step(idle=0.1, compute=0.1)
         assert m.steps == 2 and m.batches_fetched == 1
         assert m.idle_s == pytest.approx(0.4)
         assert m.stall_fraction == pytest.approx(0.4 / 0.5)
@@ -264,12 +264,12 @@ class TestFeedMetrics:
         m = FeedMetrics()
         w = StallWindow(m)
         assert w.report() is None  # no steps yet
-        m.add_step(idle=0.5, compute=0.5, depth_frac=0.0)
+        m.add_step(idle=0.5, compute=0.5)
         r = w.report()
         assert r["stall_frac"] == pytest.approx(0.5)
         assert r["steps"] == 1
         assert w.report() is None  # nothing new since
-        m.add_step(idle=0.0, compute=1.0, depth_frac=1.0)
+        m.add_step(idle=0.0, compute=1.0)
         r = w.report()
         assert r["stall_frac"] == pytest.approx(0.0)
 
