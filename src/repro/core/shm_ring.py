@@ -36,6 +36,7 @@ not only on create).
 """
 from __future__ import annotations
 
+import os
 import struct
 import threading
 import uuid
@@ -69,6 +70,25 @@ class ShmRingError(RuntimeError):
     """Ring-protocol violation (bad magic, stale seq, bad geometry)."""
 
 
+def new_segment_name() -> str:
+    """A fresh segment name that carries the pid of the process naming it,
+    so a leak sweep can tell its own segments from a concurrent process's."""
+    return f"{SEGMENT_PREFIX}{os.getpid()}_{uuid.uuid4().hex[:12]}"
+
+
+def unlink_segment(name: str) -> None:
+    """Remove a segment by name for a creator that is gone; no-op if the
+    segment was never made.  The creator shared this process's resource
+    tracker, whose registration ``unlink`` drops too."""
+    _OWNED_NAMES.discard(name)
+    try:
+        shm = shared_memory.SharedMemory(name=name)
+    except (FileNotFoundError, ValueError):  # never made, or died half made
+        return
+    shm.close()
+    shm.unlink()
+
+
 def _payload_offset(slots: int) -> int:
     raw = _HEADER.size + slots * _SLOT_REC.size
     return (raw + _PAYLOAD_ALIGN - 1) // _PAYLOAD_ALIGN * _PAYLOAD_ALIGN
@@ -95,15 +115,23 @@ class ShmRing:
     # ------------------------------------------------------------------
     @classmethod
     def create(
-        cls, slots: int = DEFAULT_SLOTS, slot_bytes: int = DEFAULT_SLOT_BYTES
+        cls,
+        slots: int = DEFAULT_SLOTS,
+        slot_bytes: int = DEFAULT_SLOT_BYTES,
+        *,
+        name: Optional[str] = None,
     ) -> "ShmRing":
-        """Create and own a new ring segment (worker side)."""
+        """Create and own a new ring segment (worker side).
+
+        ``name`` (default: a fresh ``SEGMENT_PREFIX`` name) lets another
+        process know the segment before it exists.
+        """
         slots = max(1, int(slots))
         slot_bytes = max(4096, int(slot_bytes))
         size = _payload_offset(slots) + slots * slot_bytes
         if size > MAX_RING_BYTES:
             raise ShmRingError(f"ring geometry too large: {size} bytes")
-        name = SEGMENT_PREFIX + uuid.uuid4().hex[:16]
+        name = name or new_segment_name()
         shm = shared_memory.SharedMemory(name=name, create=True, size=size)
         _OWNED_NAMES.add(shm.name)
         _HEADER.pack_into(shm.buf, 0, _MAGIC, slots, slot_bytes, 0)
@@ -111,15 +139,20 @@ class ShmRing:
         return cls(shm, slots, slot_bytes, owner=True)
 
     @classmethod
-    def attach(cls, name: str) -> "ShmRing":
-        """Attach to an existing ring by segment name (client side)."""
+    def attach(cls, name: str, *, adopt: bool = False) -> "ShmRing":
+        """Attach to an existing ring by segment name (client side).
+
+        ``adopt`` takes the segment over from a creator that shares this
+        process's resource tracker (a forked child): the registration is
+        kept, and the caller removes the segment with :func:`unlink_segment`.
+        """
         shm = shared_memory.SharedMemory(name=name)
         # CPython registers shared memory with the resource tracker on
         # ATTACH as well as create; without this unregister, the attaching
         # process's tracker unlinks the worker's segment at exit.  When the
         # attacher IS the creator's process (single-process deployments),
         # keep the registration — it belongs to the creator.
-        if shm.name not in _OWNED_NAMES:
+        if not adopt and shm.name not in _OWNED_NAMES:
             try:
                 resource_tracker.unregister(shm._name, "shared_memory")
             except Exception:
@@ -202,12 +235,11 @@ class ShmRing:
             v = self._views[slot] = self._shm.buf[a : a + self.slot_bytes]
         return v
 
+    def is_free(self, slot: int) -> bool:
+        return self._shm.buf[_HEADER.size + slot * _SLOT_REC.size] == FREE
+
     def free_slots(self) -> int:
-        return sum(
-            1
-            for i in range(self.slots)
-            if self._shm.buf[_HEADER.size + i * _SLOT_REC.size] == FREE
-        )
+        return sum(1 for i in range(self.slots) if self.is_free(i))
 
     def close(self) -> None:
         """Drop this process's mapping (best effort).

@@ -55,7 +55,7 @@ import socket
 import socketserver
 import struct
 import threading
-from typing import Any, Callable, Dict, Optional, Protocol
+from typing import Any, Callable, Dict, List, Optional, Protocol
 
 # Re-exported for backwards compatibility: payload compression used to live
 # here; it is now a pluggable registry (see codecs.py for negotiation rules).
@@ -208,9 +208,21 @@ def _send_msg(sock: socket.socket, obj: Any, method: str = "") -> None:
     spans (the response to a ``get_elements`` carries the batch)."""
     with annotate("transport.encode", method=method):
         data = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-        frame = struct.pack("<I", len(data)) + data
     with annotate("transport.send", method=method):
-        sock.sendall(frame)
+        _send_all(sock, [struct.pack("<I", len(data)), data])
+
+
+def _send_all(sock: socket.socket, parts: List[bytes]) -> None:
+    """``sendall`` of ``parts`` as gathered writes: the header goes out with
+    the payload without copying the payload after it, which for a batch of
+    large elements is a copy of hundreds of MB under the interpreter lock."""
+    views = [memoryview(p) for p in parts]
+    while views:
+        sent = sock.sendmsg(views)
+        while views and sent >= len(views[0]):
+            sent -= len(views.pop(0))
+        if views:
+            views[0] = views[0][sent:]
 
 
 def _recv_msg(sock: socket.socket, method: str = "") -> Any:
@@ -222,13 +234,17 @@ def _recv_msg(sock: socket.socket, method: str = "") -> Any:
         return pickle.loads(data)
 
 
-def _recv_exact(sock: socket.socket, n: int) -> bytes:
-    buf = b""
-    while len(buf) < n:
-        chunk = sock.recv(n - len(buf))
-        if not chunk:
+def _recv_exact(sock: socket.socket, n: int) -> bytearray:
+    """``n`` bytes, received into one buffer: appending chunk by chunk
+    recopies the prefix at every chunk, quadratic in a large frame."""
+    buf = bytearray(n)
+    view = memoryview(buf)
+    got = 0
+    while got < n:
+        k = sock.recv_into(view[got:])
+        if not k:
             raise TransportError("connection closed mid-message")
-        buf += chunk
+        got += k
     return buf
 
 

@@ -11,13 +11,30 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def _shm_segments():
-    """Names of repro ring segments currently present in /dev/shm."""
+    """Names of repro ring segments currently present in /dev/shm that this
+    process named, or whose naming process is gone.  A live segment named
+    by another process belongs to a test running there (pytest-xdist), and
+    its own sweep accounts for it."""
     from repro.core.shm_ring import SEGMENT_PREFIX
 
     try:
-        return {n for n in os.listdir("/dev/shm") if n.startswith(SEGMENT_PREFIX)}
+        names = [n for n in os.listdir("/dev/shm") if n.startswith(SEGMENT_PREFIX)]
     except OSError:  # non-Linux or odd container: nothing to sweep
         return set()
+
+    def ours(name):
+        pid = name[len(SEGMENT_PREFIX):].split("_", 1)[0]
+        if not pid.isdigit() or int(pid) == os.getpid():
+            return True
+        try:
+            os.kill(int(pid), 0)
+        except ProcessLookupError:
+            return True  # named by a process that is gone: a leak either way
+        except PermissionError:
+            pass
+        return False
+
+    return {n for n in names if ours(n)}
 
 
 @pytest.fixture(autouse=True)

@@ -17,7 +17,12 @@ import numpy as np
 import pytest
 
 from repro.data import Dataset
-from repro.data.executors import INITIAL_CREDITS, STATS_INTERVAL_S, ProcessPoolExecutor
+from repro.data.executors import (
+    INITIAL_CREDITS,
+    RING_MIN_BYTES,
+    STATS_INTERVAL_S,
+    ProcessPoolExecutor,
+)
 from repro.data.iterators import ExecContext
 from repro.obs.registry import MetricsRegistry
 from repro.obs.tracing import Tracer, annotate
@@ -41,13 +46,17 @@ def _host_events(trace_dir):
     return out
 
 
-def test_served_run_puts_the_service_spans_in_the_profiler_trace(service_factory, tmp_path):
+@pytest.mark.parametrize("width", [256, RING_MIN_BYTES // 4], ids=["small", "large"])
+def test_served_run_puts_the_service_spans_in_the_profiler_trace(service_factory, tmp_path,
+                                                                  width):
+    """Elements of at least RING_MIN_BYTES leave the pool child through its
+    ring, so ``executor.copy_out`` joins ``executor.recv``."""
     import jax
 
     from repro.feed import DeviceFeeder
 
     svc = service_factory(num_workers=1, transport="tcp", worker_processes=1)
-    dds = Dataset.range(400).map(lambda i: np.full((256,), i, np.float32)).batch(4).distribute(
+    dds = Dataset.range(400).map(lambda i: np.full((width,), i, np.float32)).batch(4).distribute(
         service=svc, processing_mode="dynamic")
     with DeviceFeeder(dds, depth=2) as feeder:
         for _ in range(3):  # warm: tasks, ring and pool child are up
@@ -55,7 +64,7 @@ def test_served_run_puts_the_service_spans_in_the_profiler_trace(service_factory
         jax.profiler.start_trace(str(tmp_path))
         try:
             with jax.profiler.TraceAnnotation("bench.window"):
-                for _ in range(12):
+                for _ in range(40):  # past what the client holds prefetched
                     jax.block_until_ready(feeder.next(timeout=60))
         finally:
             jax.profiler.stop_trace()
@@ -66,6 +75,7 @@ def test_served_run_puts_the_service_spans_in_the_profiler_trace(service_factory
                  "client.decode", "transport.recv", "transport.decode", "worker.wait",
                  "worker.encode", "executor.recv"):
         assert name in inside, (name, sorted(inside))
+    assert ("executor.copy_out" in inside) == (width >= RING_MIN_BYTES // 4)
     methods = {m.get("method") for n, s, e, m in events if n == "transport.recv"}
     assert "get_elements" in methods
 
