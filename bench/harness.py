@@ -46,6 +46,7 @@ from repro.models import build_model  # noqa: E402
 from repro.models.config import ModelConfig  # noqa: E402
 from repro.train import AdamWConfig, init_state, make_train_step, use_compile_cache  # noqa: E402
 
+from . import program as P  # noqa: E402
 from . import refops as R  # noqa: E402
 from . import stats, traffic  # noqa: E402
 from . import trace as T  # noqa: E402
@@ -137,10 +138,14 @@ def worker_counters(orchestrator: Any) -> Tuple[float, int]:
     return cpu, batches
 
 
-def counters(orchestrator: Any, client: Any, feeder: Any) -> Dict[str, float]:
+def counters(orchestrator: Any, client: Any, feeder: Any) -> Dict[str, Any]:
+    """The layers' counters: the worker ops' CPU time, the client's and the
+    feeder's metrics, and every counter of the program's registries
+    (``bench.program.counters``)."""
     cpu, produced = worker_counters(orchestrator)
     cm, fm = client.metrics, feeder.metrics
     return {
+        **P.counters(orchestrator),
         "worker_cpu_s": cpu,
         "worker_batches": produced,
         "client_fetch_s": float(cm.fetch_time),
@@ -149,6 +154,33 @@ def counters(orchestrator: Any, client: Any, feeder: Any) -> Dict[str, float]:
         "feed_transfer_s": fm.transfer_s,
         "feed_steps": fm.steps,
     }
+
+
+def window_delta(before: Dict[str, Any], after: Dict[str, Any]) -> Dict[str, Any]:
+    """Each counter's change over the window; one that first appeared in
+    the window counts from 0.  A counter the program lacks reads None."""
+    delta: Dict[str, Any] = collections.defaultdict(lambda: None)
+    for k, v in after.items():
+        b = before.get(k, 0.0)
+        delta[k] = None if v is None or b is None else v - b
+    return delta
+
+
+def step_metrics(mets: List[Dict[str, Any]]) -> Dict[str, float]:
+    """The mean over the window of every scalar of the step's metrics."""
+    mets = jax.device_get(mets)
+    keys = [k for k in (mets[0] if mets else {}) if np.ndim(mets[0][k]) == 0]
+    return {k: float(np.mean([np.asarray(m[k], np.float64) for m in mets])) for k in keys}
+
+
+def recording(step: Callable, into: List[Any]) -> Callable:
+    """``step`` that also keeps each call's metrics (traced runs only)."""
+    def call(state, batch):
+        state, met = step(state, batch)
+        into.append(met)
+        return state, met
+
+    return call
 
 
 # ---------------------------------------------------------------------------
@@ -379,6 +411,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     waits: List[float] = []
     completions: List[float] = []
     losses: List[Any] = []
+    window_mets: List[Dict[str, Any]] = []  # the step's metrics, traced runs only
     trace_dir = OUT / f"trace-{cell.name}-{seed}"
     try:
         dds = traffic.pipeline(mix, cfg, seed, cell.root).distribute(service=svc, processing_mode="dynamic")
@@ -415,6 +448,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
             client = dds.last_client
             if trace:
+                hlo = step.as_text()
+                step = recording(step, window_mets)
                 shutil.rmtree(trace_dir, ignore_errors=True)
                 jax.profiler.start_trace(str(trace_dir))
             before = counters(svc.orchestrator, client, feeder)
@@ -465,12 +500,17 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     del state, step, norms, b, met, prev, losses
     gc.collect()
 
-    delta = {k: after[k] - before[k] for k in before}
+    delta = window_delta(before, after)
     reduced = None
     if trace:
-        events = T.load(str(trace_dir))
-        reduced = T.reduce(events)
+        data = T.profile(str(trace_dir))
+        events = T.load(data, cpu_stand_in=device["platform"] == "cpu")
+        reduced = T.reduce(events, stacks=T.hlo_stacks(hlo))
+        prog_view = P.reduce(P.load(data), events)
+        del data, hlo
         shutil.rmtree(trace_dir, ignore_errors=True)
+        mean_mets = step_metrics(window_mets)
+        del window_mets
 
     # -- checks, after the window
     t0_checks = time.perf_counter()
@@ -507,6 +547,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     notes += [f"reading {k} {gaps[k]!r} (not compared in this cell)"
               for k in MODEL_NUMBERS if k not in cell.limits]
     checks.update({k: (gaps[k], cell.limits[k]) for k in MODEL_NUMBERS if k in cell.limits})
+    if trace:
+        notes.append(f"scope attribution covers {reduced['coverage']!r} of device busy time; "
+                     f"top scopes (device-s) {list(reduced['scopes'].items())[:12]}; "
+                     f"program spans {prog_view['spans']}")
     correct = failed == 0 and all(v is not None and v <= lim for v, lim in checks.values())
     lines = notes + [f"check {k} {v!r} limit {lim!r}" for k, (v, lim) in checks.items()]
 
@@ -514,9 +558,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace:
         run_view = {
             "window_s": window_s, "steps": n, "chips": cell.chips, "counters": delta,
-            "wait_s": waits, "trace": reduced,
+            "wait_s": waits, "trace": reduced, "program": prog_view,
+            "scope_s": reduced["scopes"].get, "coverage": reduced["coverage"],
+            "step_metrics": mean_mets,
+            "scope_costs": getattr(mod, "scope_costs", lambda c, b: {})(cfg, cfg["batch"]),
             "flops_per_step": mod.flops_per_step(cfg, cfg["batch"]),
             "peak_flops_per_s": peaks(device["kind"])["bf16_flops_per_s"],
+            "hbm_bytes_per_s": peaks(device["kind"])["hbm_bytes_per_s"],
         }
         metrics = {}
         for m in cell.per_layer:
@@ -526,7 +574,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         result.update(metrics=metrics, device=device,
                       breakdown={"device_ops": reduced["device_ops"],
-                                 "idle_gaps": reduced["idle_gaps"]})
+                                 "idle_gaps": reduced["idle_gaps"],
+                                 "program_gaps": prog_view["program_gaps"]})
     else:
         result.update(
             metrics={m["name"]: {"value": measured[m["name"]], "unit": m["unit"]}

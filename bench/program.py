@@ -1,53 +1,53 @@
 """The program's own spans in a profiler trace, reduced over the traced
-window, and the worker counters beside them.
+window, and the program's counters beside them.
 
-``repro.obs.tracing.Tracer.span`` opens a ``jax.profiler.TraceAnnotation``
-for every span, so a traced run's host planes hold the service's spans
-beside the benchmark's (``bench/trace.py``), one line per thread, on the
-device's clock.  :func:`load` reads them with their thread; :func:`reduce`
-gives what the per-layer readers ``feed.next_ms_p90``,
-``device.idle_fetch_share``, ``transport.recv_ms_per_batch`` and
-``worker.wait_ms_per_batch`` read, and ``program_gaps``: the first
-device's idle time in the window under each program span name open on any
-thread.  :func:`counters` reads the workers' batches served and their
-shm fallbacks for a full ring.  Where the program has no such span or
-counter (one older than them) the number is None, never an error.
+``repro.obs.tracing.Tracer.span`` and ``repro.obs.tracing.annotate`` open
+a ``jax.profiler.TraceAnnotation`` for every span, so a traced run's host
+planes hold the service's spans beside the benchmark's (``bench/trace.py``),
+one line per thread, on the device's clock.  The program names every span
+``layer.what`` in dotted lowercase (:data:`PROGRAM_NAME`); :func:`load`
+keeps every host event so named, with its thread, except the benchmark's
+own spans.  The Python tracer's frames (``$file.py:12 fn``) and the
+runtime's events (``PjitFunction(f)``, ``Foo::Bar``) do not match.
+
+:func:`reduce` gives, for every span name, its count, total seconds, p90
+duration and the first device's idle time under it (``spans``); the idle
+times again as ``program_gaps``, the ten longest; and what the readers
+``feed.next_ms_p90``, ``device.idle_fetch_share``,
+``transport.recv_ms_per_batch`` and ``worker.wait_ms_per_batch`` read.
+:func:`counters` reads every counter family of every worker's registry and
+of the process's default registry, labelled series included as
+``name{k=v}``.  Where the program has no such span or counter the number is
+None, never an error.
 """
 from __future__ import annotations
 
-import glob
-import os
+import re
 from typing import Any, Dict, List, Sequence, Tuple
 
+from . import stats
 from . import trace as T
 
-PROGRAM_SPANS = (
-    "worker.wait", "worker.encode", "executor.recv",
-    "transport.encode", "transport.send", "transport.recv", "transport.decode",
-    "client.fetch", "client.decode", "client.enqueue",
-    "feed.fetch", "feed.device_put", "feed.queue_put", "feed.next",
-)
+PROGRAM_NAME = re.compile(r"^[a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+$")
 DATA_PLANE = ("get_elements", "get_element")  # RPCs whose responses carry batches
 # name, start_ns, duration_ns, thread (plane and line), RPC method or ""
 ProgramEvent = Tuple[str, float, float, str, str]
 
 
-def load(trace_dir: str) -> List[ProgramEvent]:
-    """The program's spans of the newest trace under ``trace_dir``."""
-    from jax.profiler import ProfileData
+def is_program_span(name: str) -> bool:
+    return bool(PROGRAM_NAME.match(name)) and name not in T.HOST_SPANS
 
-    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True),
-                   key=os.path.getmtime)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+
+def load(data: Any) -> List[ProgramEvent]:
+    """The program's spans of a loaded trace (``bench.trace.profile``)."""
     out: List[ProgramEvent] = []
-    for plane in ProfileData.from_file(paths[-1]).planes:
+    for plane in data.planes:
         if not plane.name.startswith("/host:"):
             continue
         for i, line in enumerate(plane.lines):
             thread = f"{plane.name}#{i}"
             for e in line.events:
-                if e.name in PROGRAM_SPANS:
+                if is_program_span(e.name):
                     method = dict(e.stats).get("method", "")
                     out.append((e.name, float(e.start_ns), float(e.duration_ns), thread,
                                 str(method)))
@@ -79,7 +79,14 @@ def reduce(program: Sequence[ProgramEvent], events: Dict[str, Any], top: int = 1
             return None
         return sum(T.length(T.clip([(s, s + d)], lo, hi)) for s, d in picked) * ns
 
+    spans = {}
+    for name, ivs in sorted(by_name.items()):
+        inside = [b - a for a, b in ivs if lo <= a < hi]
+        spans[name] = {"count": len(inside), "total_s": T.length(T.clip(ivs, lo, hi)) * ns,
+                       "p90_s": stats.nearest_rank(inside, 0.9) * ns if inside else None,
+                       "idle_s": idle[name] * ns}
     return {
+        "spans": spans,
         "program_gaps": [[n, t * ns] for n, t in sorted(idle.items(), key=lambda kv: -kv[1])
                          if t > 0][:top],
         "idle_fetch_s": idle["feed.fetch"] * ns if "feed.fetch" in idle else None,
@@ -89,18 +96,36 @@ def reduce(program: Sequence[ProgramEvent], events: Dict[str, Any], top: int = 1
     }
 
 
+def registry_values(snapshot: Dict[str, Dict[str, Any]]) -> Dict[str, float]:
+    """A registry snapshot's counters, flat: ``name`` for the unlabelled
+    series, ``name{k=v,...}`` for each labelled one; a histogram gives its
+    ``name_count`` and ``name_sum``.  Gauges are levels, not counts, and
+    are left out."""
+    out: Dict[str, float] = {}
+    for name, fam in snapshot.items():
+        kind = fam.get("kind")
+        if kind == "counter":
+            out[name] = float(fam.get("value", 0.0))
+            for labels, v in fam.get("series", {}).items():
+                out[f"{name}{{{labels}}}"] = float(v)
+        elif kind == "histogram":
+            for labels, h in [("", fam.get("value", {})), *fam.get("series", {}).items()]:
+                tag = f"{{{labels}}}" if labels else ""
+                out[f"{name}_count{tag}"] = float(h.get("count", 0))
+                out[f"{name}_sum{tag}"] = float(h.get("sum", 0.0))
+    return out
+
+
 def counters(orchestrator: Any) -> Dict[str, Any]:
-    """Batches the workers served and the shm-channel fetches they answered
-    inline because the ring was full, summed over the workers (None where
-    the program does not count them)."""
-    served = ring_full = 0.0
-    counted = False
-    for w in orchestrator.workers:
-        snap = w.registry.snapshot()
-        served += snap.get("worker_batches_served", {}).get("value", 0.0)
-        inline = snap.get("worker_shm_inline_total")
-        if inline is not None:
-            counted = True
-            ring_full += inline.get("series", {}).get("reason=ring_full", 0.0)
-    return {"worker_batches_served": served,
-            "worker_shm_ring_full": ring_full if counted else None}
+    """Every counter of every worker's registry and of the process's
+    default registry, summed; ``worker_batches_served`` reads 0 where no
+    worker served a batch."""
+    from repro.obs.registry import get_registry
+
+    out: Dict[str, float] = {}
+    snaps = [w.registry.snapshot() for w in orchestrator.workers] + [get_registry().snapshot()]
+    for snap in snaps:
+        for k, v in registry_values(snap).items():
+            out[k] = out.get(k, 0.0) + v
+    out.setdefault("worker_batches_served", 0.0)
+    return out

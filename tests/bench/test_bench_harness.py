@@ -160,3 +160,76 @@ def test_a_run_on_the_cpu_prints_the_contract_keys_and_is_correct(cell, tiny_roo
     assert lines[-len(result["checks"]):] == [
         f"check {k} {v['value']!r} limit {v['limit']!r}" for k, v in result["checks"].items()]
     json.dumps(result)
+
+
+READERS = {
+    "extra.span_count": "def read(run):\n"
+                        "    s = run['program']['spans'].get('bench_test.extra')\n"
+                        "    return None if s is None else s['count']\n",
+    "extra.counter": "def read(run):\n    return run['counters']['bench_test_extra_total']\n",
+    "extra.scope_share": "def read(run):\n"
+                         "    t = run['scope_s']('bench_test_scope')\n"
+                         "    busy = run['trace']['busy_s'] * run['trace']['devices']\n"
+                         "    return None if not t else 100.0 * t / busy\n",
+    "extra.step_metric": "def read(run):\n    return run['step_metrics'].get('bench_test_rows')\n",
+}
+
+
+def test_a_new_span_counter_and_named_scope_reach_reader_files(tiny_root, no_compile_cache,
+                                                               monkeypatch):
+    """The files-only route: the program opens a span, bumps a counter, runs
+    a named scope and returns a metric that the benchmark was never told
+    about, and reader files added beside the others read each from a traced
+    run on the CPU."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.feed import DeviceFeeder
+    from repro.obs.registry import get_registry
+    from repro.obs.tracing import annotate
+
+    real_next, real_step = DeviceFeeder.next, harness.make_train_step
+
+    def next_with_span(self, *a, **kw):
+        with annotate("bench_test.extra"):
+            get_registry().counter("bench_test_extra_total").add(1)
+            return real_next(self, *a, **kw)
+
+    def scoped(model, opt):
+        step = real_step(model, opt)
+
+        def call(state, batch):
+            with jax.named_scope("bench_test_scope"):
+                state, met = step(state, batch)
+                rows = jnp.float32(batch["labels"].shape[0])
+            return state, dict(met, bench_test_rows=rows)
+
+        return call
+
+    monkeypatch.setattr(DeviceFeeder, "next", next_with_span)
+    monkeypatch.setattr(harness, "make_train_step", scoped)
+    # the CPU is not in the table of peaks, and a device missing there is an error
+    monkeypatch.setattr(harness, "peaks", lambda kind: {"bf16_flops_per_s": 1e12,
+                                                        "hbm_bytes_per_s": 1e11})
+    spec = json.load(open(tiny_root / "BENCHMARK.json"))
+    for name, text in READERS.items():
+        (tiny_root / "bench" / "metrics" / f"{name}.py").write_text(text)
+        spec["per_layer"].append({"name": name, "unit": "1", "better": "higher",
+                                  "source": "program_span", "layer": "test",
+                                  "moves": "steps_per_s", "workloads": ["tiny-lm.lm-tiny"]})
+    json.dump(spec, open(tiny_root / "BENCHMARK.json", "w"))
+
+    result, lines = harness.run(harness.load_cell("tiny-lm.lm-tiny", tiny_root), 3_000_000_031,
+                                0.5, True, 0.0)
+    assert result["correct"] is True, lines
+    got = {k: v["value"] for k, v in result["metrics"].items()}
+    n = result["attempted"]
+    assert got["extra.span_count"] >= n  # one per feeder.next in the window
+    assert got["extra.counter"] >= n
+    assert 0 < got["extra.scope_share"] <= 100
+    assert got["extra.step_metric"] == 2.0
+    assert "bench_test.extra" in dict(result["breakdown"]["program_gaps"]) or \
+        result["breakdown"]["program_gaps"] == []
+    assert set(result["breakdown"]) == {"device_ops", "idle_gaps", "program_gaps"}
+    assert any(line.startswith("scope attribution covers ") for line in lines)
+    json.dumps(result)
